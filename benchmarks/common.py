@@ -19,8 +19,13 @@ from repro.api import (GAConfig, GATrainer, Problem, MLPTopology,
                        GenomeSpec, HardwareCost, accuracy,
                        calibrated_seeds, exact_bespoke_baseline,
                        train_float_mlp, best_within_loss,
-                       run_batch, run_suite, state_at, front_of)
+                       run_batch, run_suite, state_at, front_of,
+                       enable_compile_cache)
 from repro.data import load_dataset, DATASETS
+
+# every JAX benchmark imports this module first: later runs reuse its
+# compiled programs from the persistent cache
+enable_compile_cache()
 
 GA_POP = 64
 GA_GENS = 60

@@ -1,9 +1,10 @@
 """Kernel micro-benchmarks: fitness-evaluation throughput (the paper's
 26M-evaluations workload) and pow2 storage savings.
 
-Wall-clock on this CPU container measures the jnp paths; the Pallas kernels
-are structural (interpret-validated) — their VMEM tiling analysis is in
-EXPERIMENTS.md §Perf.
+On a CPU host the wall clock measures the jnp paths (what ``"auto"``
+picks there), so these numbers say nothing about the TPU.
+``chip_smoke.py`` runs the kernels on the chip; ``tests/test_tpu_compile.py``
+compiles them for a v5e at the paper's widths.
 
 The fitness rows track the hot-path fusion work (dispatcher + tiling + scan
 + dedup) and are written machine-readably to ``BENCH_fitness.json`` so PRs

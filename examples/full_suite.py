@@ -17,7 +17,7 @@ import time
 
 import numpy as np
 
-from repro.api import (GAConfig, Problem, MLPTopology, GenomeSpec,
+from repro.api import (enable_compile_cache, GAConfig, Problem, MLPTopology, GenomeSpec,
                        HardwareCost, calibrated_seeds,
                        exact_bespoke_baseline, train_float_mlp,
                        best_within_loss, run_suite, suite_spec)
@@ -69,4 +69,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

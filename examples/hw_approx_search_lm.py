@@ -9,7 +9,7 @@ import jax
 
 from repro.configs import get_config
 from repro.models import build_model
-from repro.api import LMApproxSearch, FORMATS
+from repro.api import LMApproxSearch, FORMATS, enable_compile_cache
 from repro.data.tokens import synthetic_token_batch
 
 
@@ -40,4 +40,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -15,7 +15,7 @@ import sys
 
 import jax.numpy as jnp
 
-from repro.api import (GAConfig, Problem, MLPTopology, GenomeSpec,
+from repro.api import (enable_compile_cache, GAConfig, Problem, MLPTopology, GenomeSpec,
                        HardwareCost, accuracy, calibrated_seeds,
                        exact_bespoke_baseline, train_float_mlp,
                        best_within_loss, run_grid)
@@ -88,4 +88,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

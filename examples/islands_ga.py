@@ -18,7 +18,7 @@ import argparse
 
 import jax
 
-from repro.api import run_islands, IslandConfig, GAConfig, MLPTopology
+from repro.api import run_islands, IslandConfig, GAConfig, MLPTopology, enable_compile_cache
 from repro.data import load_dataset
 
 
@@ -46,4 +46,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -15,7 +15,7 @@ datasets/topologies as one padded dispatch (the whole experiment table),
 see `run_suite` in examples/full_suite.py. To serve a *stream* of such
 searches as an always-on service — and to do it fault-tolerantly
 (`Supervisor` + `FaultPolicy`: auto-checkpointing, crash recovery, lane
-quarantine, backend fallback) — see examples/serve_jobs.py.
+quarantine) — see examples/serve_jobs.py.
 
 Everything imports through ``repro.api`` — the package's stable public
 surface; scripts should not reach into ``repro.core.*`` internals.
@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 # repro.api is the package's stability boundary — examples import it and
 # nothing deeper (repro.core/* internals may move under it)
-from repro.api import (GAConfig, GATrainer, MLPTopology, GenomeSpec,
+from repro.api import (enable_compile_cache, GAConfig, GATrainer, MLPTopology, GenomeSpec,
                        HardwareCost, accuracy, calibrated_seeds,
                        exact_bespoke_baseline, train_float_mlp,
                        best_within_loss, emit_verilog, run_batch,
@@ -128,4 +128,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -32,7 +32,7 @@ import tempfile
 
 import numpy as np
 
-from repro.api import GAConfig, GATrainer, MLPTopology, Problem
+from repro.api import GAConfig, GATrainer, MLPTopology, Problem, enable_compile_cache
 from repro.data import load_dataset
 from repro.serve import (ChaosKill, ChaosPlan, FaultPolicy, SearchServer,
                          Supervisor)
@@ -154,4 +154,5 @@ def supervised_crash_demo(problems, bare_results):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

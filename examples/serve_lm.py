@@ -7,6 +7,7 @@ import argparse
 import numpy as np
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.models import build_model
 from repro.runtime.serve_loop import ServeLoop, Request
@@ -36,4 +37,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -12,6 +12,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.models import build_model
 from repro.data.tokens import synthetic_token_batch
@@ -65,4 +66,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -37,9 +37,12 @@ For long-lived or hostile environments wrap the server in a
 ``Supervisor`` under a ``FaultPolicy``: periodic auto-checkpointing
 through the two-phase-commit store, crash recovery from the latest
 *valid* checkpoint (``Supervisor.recover``), per-lane health validation
-with quarantine, capped-backoff retry of transient faults, a segment
-watchdog, and a backend fallback chain — all deterministic-fault-tested
-via ``repro.serve.chaos`` (ROADMAP "Serve-path architecture").
+with quarantine, capped-backoff retry of transient faults and a segment
+watchdog — all deterministic-fault-tested via ``repro.serve.chaos``
+(ROADMAP "Serve-path architecture").
+
+Scripts call ``enable_compile_cache()`` first, so that a later process
+finds the compiled programs in JAX's persistent compilation cache.
 """
 from __future__ import annotations
 
@@ -70,6 +73,7 @@ from .kernels import (BackendPolicy, resolve_backends,         # noqa: F401
                       BACKEND_CHOICES)
 from .serve import (SearchServer, SearchJob, JobResult,        # noqa: F401
                     LaneScheduler, Supervisor, FaultPolicy)
+from .compile_cache import enable_compile_cache                # noqa: F401
 
 __all__ = [
     # genome / problem setup
@@ -95,6 +99,8 @@ __all__ = [
     # continuous-batching search service + fault-tolerant supervision
     "SearchServer", "SearchJob", "JobResult", "LaneScheduler",
     "Supervisor", "FaultPolicy",
+    # JAX's persistent compilation cache, at one fixed directory
+    "enable_compile_cache",
 ]
 
 

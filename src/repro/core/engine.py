@@ -46,7 +46,7 @@ from .dedup import EvalCache, cache_init, dedup_eval
 from ..kernels.pop_ranking import population_ranking
 from .pareto import pareto_front
 from ..kernels.pop_mlp import population_correct
-from ..kernels import BackendPolicy
+from ..kernels.policy import BackendPolicy
 
 # "unlimited" sentinel of the per-lane generation-budget leaf: with the
 # budget gate on, a lane whose state.gen can never reach its budget is
@@ -174,8 +174,7 @@ class GAConfig:
         A bare ``dataclasses.replace(cfg, backends=...)`` re-runs
         ``__post_init__`` with the *mirrored* legacy ``*_backend`` fields
         still holding the OLD names, which silently overrides the new
-        policy back to the old one. This clears the mirrors first (the
-        serve supervisor's backend-fallback path relies on it)."""
+        policy back to the old one. This clears the mirrors first."""
         clear = {field: None for _, field in _LEGACY_BACKEND_FIELDS}
         return dataclasses.replace(self, backends=backends, **clear)
 

@@ -37,7 +37,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from .genome import GenomeSpec, MLPTopology
 from .quantize import quantize_inputs
@@ -140,7 +140,7 @@ def build_island_step(spec: GenomeSpec, cfg: IslandConfig, mesh: Mesh,
         island_round, mesh=mesh,
         in_specs=(P(),) + (pspec,) * n_carry,  # problem replicated, state sharded
         out_specs=(pspec,) * n_carry,
-        check_rep=False,
+        check_vma=False,
     ), donate_argnums=tuple(range(1, n_carry + 1)))
 
     # island i == GATrainer(seed + i)'s initial state, all islands in one

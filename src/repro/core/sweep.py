@@ -49,8 +49,8 @@ import dataclasses
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
+from jax import shard_map
 
 from . import engine
 from . import genome as genome_mod
@@ -118,6 +118,14 @@ def _run_cells(problem: Problem, seeds, pcs, pms, mals, baccs, doping,
 _run_cells_jit = jax.jit(_run_cells, static_argnames="generations")
 
 
+def _auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with Auto axis types. ``jax.make_mesh`` gives Explicit
+    axes, and a cell axis sharded over those cannot be indexed cell by
+    cell (``state_at``/``front_at``) without naming an output sharding."""
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 def _run_cells_sharded(problem: Problem, seeds, pcs, pms, mals, baccs,
                        doping, generations: int, mesh: Mesh,
                        axis_names: tuple[str, ...]):
@@ -139,10 +147,10 @@ def _run_cells_sharded(problem: Problem, seeds, pcs, pms, mals, baccs,
     fn = jax.jit(shard_map(
         lambda p, s, a, b, c, d, e: _run_cells(p, s, a, b, c, d, e,
                                                generations),
-        mesh=mesh,
+        mesh=_auto_axes(mesh),
         in_specs=(P(), pspec, pspec, pspec, pspec, pspec, P()),
         out_specs=pspec,
-        check_rep=False,
+        check_vma=False,
     ))
     out = fn(problem, seeds, pcs, pms, mals, baccs, doping)
     if pad:
@@ -332,8 +340,8 @@ def _run_suite_sharded(problem: Problem, seeds, doping, generations: int,
     pspec = P(axis_names)
     fn = jax.jit(shard_map(
         lambda p, s, d: _run_suite_cells(p, s, d, generations),
-        mesh=mesh, in_specs=(pspec, pspec, pspec), out_specs=pspec,
-        check_rep=False,
+        mesh=_auto_axes(mesh), in_specs=(pspec, pspec, pspec), out_specs=pspec,
+        check_vma=False,
     ))
     out = fn(problem, seeds, doping)
     if pad:

@@ -2,21 +2,15 @@
 pure-jnp oracle (ref.py) and a jit'd dispatch wrapper (ops.py). Validated in
 interpret mode on CPU; compiled Mosaic on TPU.
 
-This package is also the single authority on *backend selection*:
-:class:`BackendPolicy` names one backend per dispatch path (fitness /
-variation / generation / ranking) and validates the names against each
-path's ``BACKENDS`` tuple at construction — so a typo'd backend fails
-when the ``GAConfig`` is built, not at trace time deep inside a jit.
-
-It also owns the *fallback chain* (:data:`FALLBACK_CHAINS`): a policy
-naming a Pallas backend on a host whose toolchain cannot compile or
-launch it degrades along ``kernel → interpret → ref`` (ranking:
-``sweep → matrix``) instead of dying mid-trace — see
-:func:`resolve_backends` with ``fallback=True``. Availability is probed
-ONCE per process with a tiny pallas_call; each downgrade is logged once.
+This package is also the single authority on *backend selection*
+(``policy.py``): :class:`BackendPolicy` names one backend per dispatch path
+(fitness / variation / generation / ranking) and validates the names
+against each path's ``BACKENDS`` tuple at construction — so a typo'd
+backend fails when the ``GAConfig`` is built, not at trace time deep inside
+a jit. A backend that cannot compile on the host fails loudly when it is
+dispatched: on TPU ``"auto"`` always means the compiled kernel.
 """
-import dataclasses
-import warnings
+from .policy import BACKEND_CHOICES, BackendPolicy, resolve_backends
 
 from .pow2_matmul import pow2_linear, pow2_matmul, pow2_matmul_ref, pack_weights
 from .flash_attention import causal_attention, flash_attention, flash_attention_ref
@@ -26,171 +20,3 @@ from .pop_generation import population_generation, pop_generation_kernel, pop_ge
 from .pop_ranking import population_ranking, rank_select_rerank, sweep_rank
 from .ssd_scan import state_scan, ssd_state_scan, ssd_state_scan_ref
 
-from .pop_mlp.ops import BACKENDS as FITNESS_BACKENDS
-from .pop_variation.ops import BACKENDS as VARIATION_BACKENDS
-from .pop_generation.ops import BACKENDS as GENERATION_BACKENDS
-from .pop_ranking.ops import BACKENDS as RANKING_BACKENDS
-
-BACKEND_CHOICES = {
-    "fitness": FITNESS_BACKENDS,
-    "variation": VARIATION_BACKENDS,
-    "generation": GENERATION_BACKENDS,
-    "ranking": RANKING_BACKENDS,
-}
-
-
-@dataclasses.dataclass(frozen=True)
-class BackendPolicy:
-    """One validated backend name per dispatch path.
-
-    The replacement for the four stringly-typed ``GAConfig.*_backend``
-    knobs: ``GAConfig(backends=BackendPolicy(fitness="ref"))``. Every
-    field defaults to ``"auto"`` (Pallas kernel on TPU, fused jnp
-    elsewhere); unknown names raise ``ValueError`` here, at construction.
-    The old kwargs still work as deprecated aliases that populate this
-    policy (``GAConfig.__post_init__``).
-    """
-
-    fitness: str = "auto"
-    variation: str = "auto"
-    generation: str = "auto"
-    ranking: str = "auto"
-
-    def __post_init__(self):
-        for path, choices in BACKEND_CHOICES.items():
-            name = getattr(self, path)
-            if name not in choices:
-                raise ValueError(
-                    f"unknown {path} backend {name!r}: expected one of "
-                    f"{choices}")
-
-
-# Degradation order per dispatch path: a requested backend that is not
-# available on this host falls through to the next name in its chain.
-# "auto" and the pure-jnp spellings ("jnp"/"ops"/"phases"/"matrix") never
-# need a toolchain, so they are not chained — only explicit Pallas asks
-# degrade. Ranking's "sweep" is pure lax but kept chained to "matrix" as
-# the documented escape hatch for hosts where the sweep path misbehaves.
-FALLBACK_CHAINS = {
-    "fitness": ("kernel", "interpret", "ref"),
-    "variation": ("kernel", "interpret", "ref"),
-    "generation": ("kernel", "interpret", "ref"),
-    "ranking": ("sweep", "matrix"),
-}
-
-# (mode -> bool) memo for the pallas availability probe; tests reset this.
-_PALLAS_OK: dict = {}
-# downgrades already warned about, so a long-lived server logs each once.
-_WARNED: set = set()
-
-
-def _pallas_available(mode: str) -> bool:
-    """Can this process compile+launch a trivial Pallas kernel?
-
-    ``mode`` is ``"compiled"`` or ``"interpret"``. Probed with a tiny
-    (8, 128) int32 copy kernel — the minimum float32-tile-shaped launch —
-    and memoized per process. ANY failure (missing Mosaic on CPU, a
-    broken lowering, an OOM at launch) counts as unavailable: the point
-    is to degrade instead of dying mid-trace later.
-    """
-    if mode in _PALLAS_OK:
-        return _PALLAS_OK[mode]
-    try:
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import pallas as pl
-
-        def _probe_kernel(x_ref, o_ref):
-            o_ref[...] = x_ref[...] + 1
-
-        x = jnp.zeros((8, 128), jnp.int32)
-        out = pl.pallas_call(
-            _probe_kernel,
-            out_shape=jax.ShapeDtypeStruct((8, 128), jnp.int32),
-            interpret=(mode == "interpret"),
-        )(x)
-        ok = bool(jax.device_get(out)[0, 0] == 1)
-    except Exception:
-        ok = False
-    _PALLAS_OK[mode] = ok
-    return ok
-
-
-def backend_available(path: str, name: str, probe=None) -> bool:
-    """Is backend ``name`` expected to work for ``path`` on this host?
-
-    ``probe``: injectable ``(path, name) -> bool`` for tests; defaults to
-    the real pallas probe. Non-Pallas names are always available.
-    """
-    if probe is not None:
-        return bool(probe(path, name))
-    if name == "kernel":
-        return _pallas_available("compiled")
-    if name == "interpret":
-        return _pallas_available("interpret")
-    return True
-
-
-def _fallback_for(path: str, name: str, probe) -> str:
-    chain = FALLBACK_CHAINS.get(path, ())
-    if name not in chain:
-        return name
-    for cand in chain[chain.index(name):]:
-        if backend_available(path, cand, probe):
-            if cand != name and (path, name, cand) not in _WARNED:
-                _WARNED.add((path, name, cand))
-                warnings.warn(
-                    f"{path} backend {name!r} unavailable on this host; "
-                    f"falling back to {cand!r}", RuntimeWarning,
-                    stacklevel=3)
-            return cand
-    # nothing in the chain probes healthy: keep the last (pure) entry so
-    # the failure, if any, surfaces in the dispatch itself.
-    last = chain[-1]
-    if last != name and (path, name, last) not in _WARNED:
-        _WARNED.add((path, name, last))
-        warnings.warn(
-            f"{path} backend {name!r} unavailable and no probed fallback; "
-            f"using {last!r}", RuntimeWarning, stacklevel=3)
-    return last
-
-
-def apply_fallbacks(policy: BackendPolicy, probe=None) -> BackendPolicy:
-    """Degrade any unavailable backend along :data:`FALLBACK_CHAINS`.
-
-    Pure with respect to the policy (returns a new frozen instance);
-    warns once per process per (path, from → to) downgrade.
-    """
-    repl = {}
-    for path in BACKEND_CHOICES:
-        name = getattr(policy, path)
-        picked = _fallback_for(path, name, probe)
-        if picked != name:
-            repl[path] = picked
-    return dataclasses.replace(policy, **repl) if repl else policy
-
-
-def resolve_backends(policy=None, *, fallback: bool = False, probe=None,
-                     **overrides) -> BackendPolicy:
-    """THE resolver from loose backend names to a validated policy.
-
-    ``policy``: an existing :class:`BackendPolicy` (or None for all-auto).
-    ``overrides``: per-path names (``fitness=…``, ``ranking=…``, …); a
-    ``None`` override means "keep the policy's choice". Unknown path or
-    backend names raise ``ValueError``. Returns a (possibly new) frozen
-    ``BackendPolicy``.
-
-    ``fallback=True`` additionally degrades backends this host cannot
-    launch along :data:`FALLBACK_CHAINS` (kernel → interpret → ref;
-    ranking: sweep → matrix), warning once per downgrade — the knob
-    ``FaultPolicy.backend_fallback`` flips in the supervised serve path.
-    ``probe``: injectable availability predicate for tests.
-    """
-    base = policy if policy is not None else BackendPolicy()
-    bad = set(overrides) - set(BACKEND_CHOICES)
-    if bad:
-        raise ValueError(f"unknown backend paths {sorted(bad)}: expected "
-                         f"a subset of {sorted(BACKEND_CHOICES)}")
-    kept = {k: v for k, v in overrides.items() if v is not None}
-    out = dataclasses.replace(base, **kept) if kept else base
-    return apply_fallbacks(out, probe) if fallback else out

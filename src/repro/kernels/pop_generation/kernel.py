@@ -31,16 +31,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ...core.genome import GenomeSpec
-from ..pop_mlp.kernel import _forward_block
+from ..pop_mlp.kernel import (count_correct, count_correct_mc,
+                              sample_operands, sample_specs, valid_samples)
 from ..pop_variation.kernel import _slot_uniform
 
 
 def _kernel(a_ref, b_ref, do_ref, low_ref, high_ref, ismask_ref, bits_ref,
             ids_ref, keys_ref, pm_ref, x_ref, y_ref, samp_ref, om_ref,
             *refs, spec: GenomeSpec, bp: int, half: int,
-            bs: int, n_valid: int, n_dev: int | None = None):
+            bs: int, n_valid: int, mc: bool):
     # trailing refs: [dev_ref] (device-variation MC only), child_ref, cnt_ref
-    dev_ref = refs[0] if n_dev is not None else None
     child_ref, cnt_ref = refs[-2], refs[-1]
     # program_id must stay outside the traced-cond bodies: the interpret-mode
     # impl only substitutes it at kernel top level (see pop_mlp.kernel)
@@ -79,34 +79,15 @@ def _kernel(a_ref, b_ref, do_ref, low_ref, high_ref, ismask_ref, bits_ref,
     # suite fast path: all-padding sample tiles (label −1) are skipped
     @pl.when(start < samp_ref[0, 0])
     def _fitness():
-        y = y_ref[...][:, 0][None, :]
-        om = om_ref[...][:, None, :] > 0
-        valid = (start + jax.lax.broadcasted_iota(jnp.int32, (bp, bs), 1)
-                 ) < n_valid
-        if n_dev is None:
-            logits = _forward_block(child_ref[...], x_ref[...], spec)
-            logits = jnp.where(om, logits, jnp.iinfo(jnp.int32).min)
-            pred = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (bp, bs)
-            correct = (pred == y).astype(jnp.int32)
-            cnt_ref[...] += jnp.sum(jnp.where(valid, correct, 0), axis=1,
-                                    keepdims=True)
-            return
-        # device-variation MC: the child block stays resident in VMEM
-        # while the K perturbed instances each rerun the forward pass
-        # (same unrolled loop as pop_mlp._kernel_mc)
-        child = child_ref[...]
-        hi = high_ref[...]                                       # (1, G)
-        dev = dev_ref[...]
-        cols = []
-        for k in range(n_dev):
-            d = dev[k][None, :]                                  # (1, G)
-            gk = jnp.where(d == 0, child, jnp.clip(child + d, 0, hi - 1))
-            logits = _forward_block(gk, x_ref[...], spec)
-            logits = jnp.where(om, logits, jnp.iinfo(jnp.int32).min)
-            pred = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            correct = (pred == y).astype(jnp.int32)
-            cols.append(jnp.sum(jnp.where(valid, correct, 0), axis=1))
-        cnt_ref[...] += jnp.stack(cols, axis=-1)
+        args = (x_ref[...], y_ref[...], om_ref[...],
+                valid_samples(start, n_valid, bp, bs), spec)
+        if mc:
+            # device-variation MC: the child block stays resident in VMEM
+            # while the K perturbed instances each rerun the forward pass
+            cnt_ref[...] += count_correct_mc(child_ref[...], refs[0][...],
+                                             high_ref[...], *args)
+        else:
+            cnt_ref[...] += count_correct(child_ref[...], *args)
 
 
 @functools.partial(jax.jit,
@@ -144,25 +125,17 @@ def pop_generation_kernel(a_rows, b_rows, do_rows, table_low, table_high,
         a_rows = jnp.pad(a_rows, ((0, pad_p), (0, 0)))
         b_rows = jnp.pad(b_rows, ((0, pad_p), (0, 0)))
         do_rows = jnp.pad(do_rows.astype(jnp.int32), (0, pad_p))
-    pad_s = (bs - S % bs) % bs
-    if pad_s:
-        x_int = jnp.pad(x_int, ((0, pad_s), (0, 0)))
-        labels = jnp.pad(labels, (0, pad_s), constant_values=-1)
-    n_s = (S + pad_s) // bs
-    samp = jnp.full((1, 1), S if n_valid_samples is None else n_valid_samples,
-                    jnp.int32)
-    om = (jnp.ones((1, n_out), jnp.int32) if out_mask is None
-          else jnp.asarray(out_mask, jnp.int32).reshape(1, n_out))
+    xt, y, samp, om, n_s = sample_operands(x_int, labels, bs,
+                                           n_valid_samples, out_mask, n_out)
     row2d = lambda arr: jnp.asarray(arr, jnp.int32).reshape(-1, 1)
     gene2d = lambda arr, dt: jnp.asarray(arr, dt).reshape(1, G)
-    n_dev = None if dev is None else dev.shape[0]
-    nc = 1 if n_dev is None else n_dev
-    dev_specs = ([] if n_dev is None
-                 else [pl.BlockSpec((n_dev, G), lambda i, j: (0, 0))])
-    dev_ops = () if n_dev is None else (jnp.asarray(dev, jnp.int32),)
+    nc = 1 if dev is None else dev.shape[0]
+    dev_specs = ([] if dev is None
+                 else [pl.BlockSpec((nc, G), lambda i, j: (0, 0))])
+    dev_ops = () if dev is None else (jnp.asarray(dev, jnp.int32),)
     children, counts = pl.pallas_call(
         functools.partial(_kernel, spec=spec, bp=bp, half=half, bs=bs,
-                          n_valid=S, n_dev=n_dev),
+                          n_valid=S, mc=dev is not None),
         grid=((P + pad_p) // bp, n_s),
         in_specs=[
             pl.BlockSpec((bp, G), lambda i, j: (i, 0)),     # a_rows
@@ -175,10 +148,7 @@ def pop_generation_kernel(a_rows, b_rows, do_rows, table_low, table_high,
             pl.BlockSpec((1, G), lambda i, j: (0, 0)),      # draw ids
             pl.BlockSpec((3, 2), lambda i, j: (0, 0)),      # slot keys
             pl.BlockSpec((1, 1), lambda i, j: (0, 0)),      # pm_gene
-            pl.BlockSpec((bs, x_int.shape[1]), lambda i, j: (j, 0)),
-            pl.BlockSpec((bs, 1), lambda i, j: (j, 0)),     # labels (2-D)
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),      # n_valid_samples
-            pl.BlockSpec((1, n_out), lambda i, j: (0, 0)),  # output-col mask
+            *sample_specs(x_int.shape[1], bs, n_out),
             *dev_specs,                                     # device deltas
         ],
         out_specs=[pl.BlockSpec((bp, G), lambda i, j: (i, 0)),
@@ -191,5 +161,5 @@ def pop_generation_kernel(a_rows, b_rows, do_rows, table_low, table_high,
       gene2d(table_mask_bits, jnp.int32), gene2d(table_ids, jnp.uint32),
       jnp.asarray(slot_keys, jnp.uint32),
       jnp.asarray(pm_gene, jnp.float32).reshape(1, 1),
-      x_int, labels[:, None], samp, om, *dev_ops)
-    return children[:P], (counts[:P, 0] if n_dev is None else counts[:P])
+      xt, y, samp, om, *dev_ops)
+    return children[:P], (counts[:P, 0] if dev is None else counts[:P])

@@ -35,8 +35,9 @@ from ...core.operators import variation_keys
 from ..pop_variation.ops import _VARIATION_SLOTS
 from .ref import pop_generation_jnp, _rank_and_select
 from .kernel import pop_generation_kernel
+from ..policy import BACKEND_CHOICES
 
-BACKENDS = ("auto", "kernel", "interpret", "ref", "phases")
+BACKENDS = BACKEND_CHOICES["generation"]
 
 
 def _generation_kernel(problem, state, interpret: bool, active=None):

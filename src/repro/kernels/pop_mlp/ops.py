@@ -33,8 +33,9 @@ import jax
 from .kernel import pop_mlp_correct, pop_mlp_correct_mc
 from .ref import (pop_mlp_correct_ref, pop_mlp_correct_tiled,
                   pop_mlp_correct_mc as pop_mlp_correct_mc_ref)
+from ..policy import BACKEND_CHOICES
 
-BACKENDS = ("auto", "kernel", "interpret", "ref", "jnp")
+BACKENDS = BACKEND_CHOICES["fitness"]
 
 
 def population_correct(pop, x_int, labels, *, spec, backend=None,

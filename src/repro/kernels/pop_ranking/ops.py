@@ -32,8 +32,9 @@ from ...core.nsga2 import (crowding_distance, dominance_matrix,
                            evaluate_ranking, ranking_from_dom,
                            subset_ranking, survivor_select)
 from .sweep import sweep_rank, sweep_ranking
+from ..policy import BACKEND_CHOICES
 
-BACKENDS = ("auto", "sweep", "matrix")
+BACKENDS = BACKEND_CHOICES["ranking"]
 
 
 def _resolve(backend: str | None) -> str:

@@ -32,8 +32,9 @@ from ...core.nsga2 import tournament_select
 from ...core.operators import make_offspring, variation_keys
 from .ref import pop_variation_ref
 from .kernel import pop_variation_kernel
+from ..policy import BACKEND_CHOICES
 
-BACKENDS = ("auto", "kernel", "interpret", "ref", "ops")
+BACKENDS = BACKEND_CHOICES["variation"]
 
 _VARIATION_SLOTS = (SLOT_CROSS_SWAP, SLOT_MUT_DO, SLOT_MUT_VAL)
 

@@ -21,7 +21,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..configs.base import ArchConfig, MoEConfig
 from .params import ParamDecl
@@ -223,7 +223,7 @@ def moe_ffn(cfg: ArchConfig, p: dict, x: jnp.ndarray, mesh=None,
             local, mesh=mesh,
             in_specs=(tok_spec, P(None, None), wspec_g, wspec_g, wspec_d),
             out_specs=(tok_spec, P()),
-            check_rep=False,
+            check_vma=False,
         )(xf, p["router"]["w"], p["gate"]["w"], p["up"]["w"], p["down"]["w"])
     else:
         y, aux = _local_moe(m, cfg.quant, None, None, xf, p["router"]["w"],

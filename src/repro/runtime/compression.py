@@ -19,7 +19,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 @dataclasses.dataclass
@@ -101,5 +101,5 @@ def pod_compressed_grads(loss_fn, params, batch, mesh, errors):
     fn = shard_map(per_pod, mesh=mesh,
                    in_specs=(spec_params, P("pod"), spec_params),
                    out_specs=(spec_params, (P(), spec_params), spec_params),
-                   check_rep=False)
+                   check_vma=False)
     return fn(params, batch, errors)

@@ -29,10 +29,6 @@ its segment loop under a :class:`FaultPolicy`:
   * **watchdog** — ``segment_timeout_s`` bounds one segment's wall
     clock; a hung segment raises :class:`SegmentTimeoutError` (fatal,
     never retried in-process) instead of eating the host forever.
-  * **backend fallback** — :meth:`for_problems` resolves the jobs'
-    ``BackendPolicy`` with ``fallback=True`` first, so a host that
-    cannot launch the requested Pallas backend degrades kernel →
-    interpret → ref (warned once) rather than dying at first dispatch.
   * **convergence retirement** — with ``patience=N`` a lane whose
     Pareto front fingerprint is unchanged for N consecutive segments
     retires early (``converged=True``); off by default and bit-identical
@@ -54,7 +50,6 @@ import jax
 
 from ..core import engine
 from ..checkpoint import manager as ckpt
-from ..kernels import resolve_backends
 from .chaos import ChaosPlan, SegmentFault
 from .jobs import JobResult
 from .server import SearchServer
@@ -86,9 +81,7 @@ class FaultPolicy:
     for transient faults (delay ``base * 2^attempt`` capped at ``cap``).
     ``patience``: consecutive unchanged-front segments before early
     retirement (0 = off). ``segment_timeout_s``: per-segment watchdog
-    (None = off). ``backend_fallback``: let :meth:`Supervisor.
-    for_problems` degrade unavailable backends along
-    ``kernels.FALLBACK_CHAINS``.
+    (None = off).
     """
     checkpoint_every: int = 0
     keep: int = 3
@@ -99,7 +92,6 @@ class FaultPolicy:
     backoff_cap_s: float = 2.0
     patience: int = 0
     segment_timeout_s: Optional[float] = None
-    backend_fallback: bool = True
 
 
 def _validate_lanes(problems, states):
@@ -161,10 +153,9 @@ class Supervisor:
     def for_problems(cls, problems, policy: Optional[FaultPolicy] = None,
                      *, directory: Optional[str] = None,
                      chaos: Optional[ChaosPlan] = None, sleep=time.sleep,
-                     probe=None, scheduler_policy: Optional[str] = None,
+                     scheduler_policy: Optional[str] = None,
                      **server_kw) -> "Supervisor":
-        """Build server + supervisor in one go, degrading any backend
-        this host cannot launch first (``policy.backend_fallback``).
+        """Build server + supervisor in one go.
 
         ``policy`` here is the :class:`FaultPolicy`; the lane scheduler's
         admission policy (the server's ``policy`` kwarg) rides as
@@ -172,15 +163,6 @@ class Supervisor:
         policy = policy if policy is not None else FaultPolicy()
         if scheduler_policy is not None:
             server_kw["policy"] = scheduler_policy
-        problems = list(problems)
-        if policy.backend_fallback:
-            cfg = problems[0].cfg
-            backends = resolve_backends(cfg.backends, fallback=True,
-                                        probe=probe)
-            if backends != cfg.backends:
-                new_cfg = cfg.with_backends(backends)
-                problems = [dataclasses.replace(p, cfg=new_cfg)
-                            for p in problems]
         server = SearchServer.for_problems(problems, **server_kw)
         return cls(server, policy, directory=directory, chaos=chaos,
                    sleep=sleep)

@@ -41,9 +41,12 @@ def ref_rank(obj, viol):
 
 
 # allow_subnormal=False: the jax CPU backend enables FTZ globally, which
-# trips hypothesis's subnormal sanity check.
+# trips hypothesis's subnormal sanity check. width=32: the draws become
+# float32 arrays, so they must not be float32 subnormals either (and the
+# bounds must be float32 values).
 def _f(lo, hi):
-    return st.floats(lo, hi, allow_nan=False, allow_subnormal=False)
+    return st.floats(float(np.float32(lo)), float(np.float32(hi)),
+                     allow_nan=False, allow_subnormal=False, width=32)
 
 
 objs = st.lists(st.tuples(_f(0, 1), _f(0, 100), _f(0, 0.2)),

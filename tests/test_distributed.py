@@ -45,6 +45,38 @@ def test_island_ga_runs_and_dominates_random():
 
 
 @pytest.mark.slow
+def test_sharded_cells_match_one_device_cell_by_cell():
+    """run_suite and run_grid over a 4-device ``jax.make_mesh`` (Explicit
+    axes by default) equal the one-device runs, peeled cell by cell."""
+    out = run_sub("""
+        import numpy as np, jax
+        from repro.core import GAConfig, engine, sweep
+        from repro.core.genome import MLPTopology
+        from repro.data import load_dataset
+        cfg = GAConfig(pop_size=8, generations=2)
+        probs = [engine.Problem.from_data(MLPTopology(d.topology),
+                                          d.x_train[:120], d.y_train[:120],
+                                          cfg)
+                 for d in (load_dataset("breast_cancer"),
+                           load_dataset("redwine"))]
+        mesh = jax.make_mesh((4,), ("data",))
+        for run in (lambda **kw: sweep.run_suite(probs, [0, 1, 2, 3], **kw),
+                    lambda **kw: sweep.run_grid(probs[0], [0, 1, 2],
+                                                **kw)):
+            a, b = run(mesh=mesh), run()
+            for i in range(a.n_cells):
+                for k in ("objectives", "genomes"):
+                    np.testing.assert_array_equal(a.front_at(i)[k],
+                                                  b.front_at(i)[k])
+                np.testing.assert_array_equal(
+                    np.asarray(a.state_at(i).pop),
+                    np.asarray(b.state_at(i).pop))
+        print("CELLS_OK")
+    """)
+    assert "CELLS_OK" in out
+
+
+@pytest.mark.slow
 def test_sharded_moe_matches_local():
     out = run_sub("""
         import numpy as np, jax, jax.numpy as jnp

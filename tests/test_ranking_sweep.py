@@ -17,9 +17,12 @@ from repro.kernels.pop_ranking import (population_ranking,
 
 
 # allow_subnormal=False: the jax CPU backend enables FTZ globally, which
-# trips hypothesis's subnormal sanity check.
+# trips hypothesis's subnormal sanity check. width=32: the draws become
+# float32 arrays, so they must not be float32 subnormals either (and the
+# bounds must be float32 values).
 def _f(lo, hi):
-    return st.floats(lo, hi, allow_nan=False, allow_subnormal=False)
+    return st.floats(float(np.float32(lo)), float(np.float32(hi)),
+                     allow_nan=False, allow_subnormal=False, width=32)
 
 
 # continuous objectives: ties are rare, fronts are thin

@@ -1,6 +1,6 @@
 """Fault-tolerant serve supervision (`repro.serve.supervisor`) —
 fault-free behavior, checkpoint cadence, convergence retirement, the
-watchdog, quarantine plumbing and the backend fallback chain.
+watchdog and quarantine plumbing.
 
 The do-no-harm contract: a default-policy Supervisor over a fault-free
 stream retires every job bit-identical to the bare ``SearchServer`` (and
@@ -19,8 +19,7 @@ from repro.core import GAConfig, GATrainer
 from repro.core import engine
 from repro.core.genome import MLPTopology
 
-import repro.kernels as kernels              # noqa: E402 — after repro.core:
-from repro.kernels import BackendPolicy, resolve_backends  # import cycle
+from repro.kernels import BackendPolicy
 from repro.serve import (FaultPolicy, LaneValidationError, SearchServer,
                          SegmentTimeoutError, Supervisor)
 from repro.serve.chaos import ChaosPlan
@@ -226,61 +225,7 @@ class TestValidateState:
         assert bool(engine.validate_ok(p, st))
 
 
-class TestBackendFallback:
-    @pytest.fixture(autouse=True)
-    def _fresh_memo(self, monkeypatch):
-        monkeypatch.setattr(kernels, "_PALLAS_OK", {})
-        monkeypatch.setattr(kernels, "_WARNED", set())
-
-    def test_unavailable_kernel_degrades_down_the_chain(self):
-        probe = lambda path, name: name not in ("kernel",)   # noqa: E731
-        with pytest.warns(RuntimeWarning, match="falling back"):
-            got = resolve_backends(BackendPolicy(fitness="kernel"),
-                                   fallback=True, probe=probe)
-        assert got.fitness == "interpret"
-
-    def test_degrades_to_ref_when_interpret_also_fails(self):
-        probe = lambda path, name: name in ("ref", "matrix")  # noqa: E731
-        with pytest.warns(RuntimeWarning):
-            got = resolve_backends(
-                BackendPolicy(fitness="kernel", variation="interpret",
-                              ranking="sweep"),
-                fallback=True, probe=probe)
-        assert got.fitness == "ref"
-        assert got.variation == "ref"
-        assert got.ranking == "matrix"
-
-    def test_available_backend_untouched_no_warning(self):
-        import warnings as w
-        probe = lambda path, name: True                      # noqa: E731
-        pol = BackendPolicy(fitness="interpret", ranking="sweep")
-        with w.catch_warnings():
-            w.simplefilter("error")
-            got = resolve_backends(pol, fallback=True, probe=probe)
-        assert got == pol
-
-    def test_warns_once_per_downgrade(self):
-        import warnings as w
-        probe = lambda path, name: name != "kernel"          # noqa: E731
-        with pytest.warns(RuntimeWarning):
-            resolve_backends(BackendPolicy(fitness="kernel"),
-                             fallback=True, probe=probe)
-        with w.catch_warnings():
-            w.simplefilter("error")        # second resolve: silent
-            resolve_backends(BackendPolicy(fitness="kernel"),
-                             fallback=True, probe=probe)
-
-    def test_fallback_off_preserves_policy(self):
-        probe = lambda path, name: False                     # noqa: E731
-        pol = BackendPolicy(fitness="kernel")
-        assert resolve_backends(pol, probe=probe) == pol
-
-    def test_real_probe_interpret_mode_works_here(self):
-        """Interpret-mode Pallas must be launchable wherever the test
-        suite runs (it is how CI validates every kernel)."""
-        assert kernels.backend_available("fitness", "interpret")
-        assert kernels.backend_available("fitness", "ref")
-
+class TestBackendPolicy:
     def test_with_backends_beats_the_legacy_mirror(self, two_problems):
         """Regression: a bare dataclasses.replace(cfg, backends=...) is
         silently overridden by the mirrored legacy *_backend fields;
@@ -288,12 +233,3 @@ class TestBackendFallback:
         (_, pa), _, _ = two_problems
         pol = BackendPolicy(fitness="interpret")
         assert pa.cfg.with_backends(pol).backends.fitness == "interpret"
-
-    def test_supervisor_applies_fallback_at_build(self, two_problems):
-        (_, pa), _, _ = two_problems
-        cfg = pa.cfg.with_backends(BackendPolicy(fitness="interpret"))
-        p = dataclasses.replace(pa, cfg=cfg)
-        probe = lambda path, name: name != "interpret"       # noqa: E731
-        with pytest.warns(RuntimeWarning):
-            sup = Supervisor.for_problems([p], probe=probe, n_lanes=1)
-        assert sup.server._cfg.backends.fitness == "ref"
