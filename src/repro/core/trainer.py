@@ -30,11 +30,11 @@ saved.
 """
 from __future__ import annotations
 
-import time
 from typing import Optional, Sequence
 
 import numpy as np
 import jax
+from jax.profiler import TraceAnnotation
 
 from .genome import MLPTopology
 from . import engine
@@ -78,9 +78,10 @@ class GATrainer:
 
     # -- init ---------------------------------------------------------------
     def init_state(self) -> GAState:
-        state, n_eval = self._init_jit(
-            self.problem, engine._doping_array(self.doping_seeds))
-        self._init_unique_evals = int(n_eval)
+        with TraceAnnotation("ga.init"):
+            state, n_eval = self._init_jit(
+                self.problem, engine._doping_array(self.doping_seeds))
+            self._init_unique_evals = int(n_eval)
         return state
 
     # -- public API ----------------------------------------------------------
@@ -92,23 +93,21 @@ class GATrainer:
         ``lax.scan`` dispatch; ``scan=False`` keeps the per-generation
         Python loop (seed semantics — bit-identical results).
 
-        History ``time_s`` caveat: a scanned run has no per-generation
-        wall clock (one dispatch covers the whole run), so ``time_s`` is
-        the total elapsed time apportioned linearly across generations;
-        only ``scan=False`` records measured cumulative timestamps."""
+        With ``verbose`` the history holds, every ``cfg.log_every``
+        generations and at the last, ``gen``, ``best_err`` and
+        ``best_area``."""
         gens = generations if generations is not None else self.cfg.generations
         scan = self.cfg.scan if scan is None else scan
         state = self.init_state()
         history = []
-        t0 = time.time()
         if scan and gens > 0:
-            state, (best_err, best_area, n_eval, n_hit) = self._scan_jit(
-                self.problem, state, generations=gens)
-            jax.block_until_ready(state.pop)
-            elapsed = time.time() - t0
-            self.unique_evals = (int(np.asarray(n_eval).sum())
-                                 + self._init_unique_evals)
-            self.cache_hits = int(np.asarray(n_hit).sum())
+            with TraceAnnotation("ga.scan"):
+                state, (best_err, best_area, n_eval, n_hit) = self._scan_jit(
+                    self.problem, state, generations=gens)
+                jax.block_until_ready(state.pop)
+                self.unique_evals = (int(np.asarray(n_eval).sum())
+                                     + self._init_unique_evals)
+                self.cache_hits = int(np.asarray(n_hit).sum())
             if verbose:
                 for g in range(gens):
                     if g % self.cfg.log_every == 0 or g == gens - 1:
@@ -116,8 +115,6 @@ class GATrainer:
                             "gen": g,
                             "best_err": float(best_err[g]),
                             "best_area": float(best_area[g]),
-                            # apportioned, not measured — see docstring
-                            "time_s": elapsed * (g + 1) / gens,
                         })
         else:
             self.unique_evals = None
@@ -131,7 +128,6 @@ class GATrainer:
                         "gen": g,
                         "best_err": float(err.min()),
                         "best_area": float(area.min()),
-                        "time_s": time.time() - t0,
                     })
         jax.block_until_ready(state.pop)
         self.evaluations = (gens + 1) * self.cfg.pop_size * int(self.labels.shape[0])
@@ -139,4 +135,5 @@ class GATrainer:
 
     def front(self, state: GAState):
         """Feasible estimated Pareto front (paper Fig. 2 output)."""
-        return engine.front_of(state)
+        with TraceAnnotation("ga.front"):
+            return engine.front_of(state)

@@ -52,35 +52,41 @@ def _generation_kernel(problem, state, interpret: bool, active=None):
     P = state.pop.shape[0]
     if P % 2:
         raise ValueError(f"variation needs an even population, got {P}")
-    key, k_off = jax.random.split(state.key)
-    k_sel, k_cx, k_var = variation_keys(k_off)
-    parents = tournament_select(k_sel, state.rank, state.crowd, P)
-    pa = state.pop[parents[: P // 2]]
-    pb = state.pop[parents[P // 2:]]
-    do_cx = jax.random.uniform(k_cx, (P // 2,)) < problem.crossover_rate
-    # child frame: row p < P/2 is pair p as (a=pa, b=pb); row P/2 + p the
-    # same pair with roles flipped — see pop_variation.ops
-    a_rows = jnp.concatenate([pa, pb], axis=0)
-    b_rows = jnp.concatenate([pb, pa], axis=0)
-    do_rows = jnp.concatenate([do_cx, do_cx])
-    n_samp = problem.n_valid_samples
-    if cfg.batch_axis is not None:
-        n_samp = jax.lax.pmax(n_samp, cfg.batch_axis)
-    dev = engine.device_deltas(problem) if engine.variation_on(cfg) else None
+    # named scopes group the XLA phases around the kernel in a profile;
+    # the kernel stays outside them, found by its own name
+    with jax.named_scope("ga.parents"):
+        key, k_off = jax.random.split(state.key)
+        k_sel, k_cx, k_var = variation_keys(k_off)
+        var_keys = _slot_keys(k_var, _VARIATION_SLOTS)
+        parents = tournament_select(k_sel, state.rank, state.crowd, P)
+        pa = state.pop[parents[: P // 2]]
+        pb = state.pop[parents[P // 2:]]
+        do_cx = jax.random.uniform(k_cx, (P // 2,)) < problem.crossover_rate
+        # child frame: row p < P/2 is pair p as (a=pa, b=pb); row P/2 + p
+        # the same pair with roles flipped — see pop_variation.ops
+        a_rows = jnp.concatenate([pa, pb], axis=0)
+        b_rows = jnp.concatenate([pb, pa], axis=0)
+        do_rows = jnp.concatenate([do_cx, do_cx])
+    with jax.named_scope("ga.fitness"):
+        n_samp = problem.n_valid_samples
+        if cfg.batch_axis is not None:
+            n_samp = jax.lax.pmax(n_samp, cfg.batch_axis)
+        dev = (engine.device_deltas(problem) if engine.variation_on(cfg)
+               else None)
     children, child_counts = pop_generation_kernel(
         a_rows, b_rows, do_rows, t.low, t.high, t.is_mask, t.mask_bits,
-        t.ids, _slot_keys(k_var, _VARIATION_SLOTS),
-        problem.mutation_rate_gene, problem.x_int, problem.labels,
-        spec=problem.spec, bp=min(cfg.pop_tile, 8),
+        t.ids, var_keys, problem.mutation_rate_gene, problem.x_int,
+        problem.labels, spec=problem.spec, bp=min(cfg.pop_tile, 8),
         bs=min(cfg.sample_tile, 128), interpret=interpret,
         n_valid_samples=n_samp, out_mask=problem.out_mask, dev=dev)
-    pop = jnp.concatenate([state.pop, children], axis=0)
-    if engine.dedup_mode(cfg) != "off":
-        counts = jnp.concatenate([state.counts, child_counts])
-    else:
-        counts = jnp.zeros((2 * P,) + state.counts.shape[1:], jnp.int32)
-    c_obj, c_viol = engine.objectives(
-        problem, children, engine.counts_accuracy(problem, child_counts))
+    with jax.named_scope("ga.objectives"):
+        pop = jnp.concatenate([state.pop, children], axis=0)
+        if engine.dedup_mode(cfg) != "off":
+            counts = jnp.concatenate([state.counts, child_counts])
+        else:
+            counts = jnp.zeros((2 * P,) + state.counts.shape[1:], jnp.int32)
+        c_obj, c_viol = engine.objectives(
+            problem, children, engine.counts_accuracy(problem, child_counts))
     # the megakernel evaluates every child regardless of ``active`` (its
     # win is VMEM fusion, not row skipping) — only the accounting is
     # gated, so a retired lane reports zero evaluations like the jnp path

@@ -34,12 +34,16 @@ def _rank_and_select(state, pop, counts, c_obj, c_viol, key, cache,
     the O(P log P) sweep by default, the dominance-matrix oracle on
     ``backend="matrix"`` — with bit-identical survivors either way."""
     P = state.pop.shape[0]
-    obj = jnp.concatenate([state.obj, c_obj], axis=0)
-    viol = jnp.concatenate([state.viol, c_viol], axis=0)
-    keep, rank2, crowd2 = rank_select_rerank(obj, viol, P, backend=backend)
-    new = type(state)(pop[keep], obj[keep], viol[keep], rank2, crowd2,
-                      counts[keep], key, state.gen + 1, cache)
-    aux = (new.obj[:, 0].min(), new.obj[:, 1].min(), n_eval, n_hit)
+    with jax.named_scope("ga.objectives"):
+        obj = jnp.concatenate([state.obj, c_obj], axis=0)
+        viol = jnp.concatenate([state.viol, c_viol], axis=0)
+    with jax.named_scope("ga.rank"):
+        keep, rank2, crowd2 = rank_select_rerank(obj, viol, P,
+                                                 backend=backend)
+    with jax.named_scope("ga.survive"):
+        new = type(state)(pop[keep], obj[keep], viol[keep], rank2, crowd2,
+                          counts[keep], key, state.gen + 1, cache)
+        aux = (new.obj[:, 0].min(), new.obj[:, 1].min(), n_eval, n_hit)
     return new, aux
 
 
@@ -58,39 +62,51 @@ def pop_generation_jnp(problem, state, use_cache: bool = True, active=None):
 
     cfg = problem.cfg
     P = state.pop.shape[0]
-    key, k_off = jax.random.split(state.key)
-    children = population_variation(
-        k_off, state.pop, state.rank, state.crowd, genes=problem.genes,
-        pc=problem.crossover_rate, pm=problem.mutation_rate_gene,
-        backend=cfg.backends.variation, pop_tile=cfg.pop_tile)
-    pop = jnp.concatenate([state.pop, children], axis=0)
+    with jax.named_scope("ga.variation"):
+        key, k_off = jax.random.split(state.key)
+        children = population_variation(
+            k_off, state.pop, state.rank, state.crowd, genes=problem.genes,
+            pc=problem.crossover_rate, pm=problem.mutation_rate_gene,
+            backend=cfg.backends.variation, pop_tile=cfg.pop_tile)
+    with jax.named_scope("ga.objectives"):
+        pop = jnp.concatenate([state.pop, children], axis=0)
 
     mode = engine.dedup_mode(cfg)
     cache = state.cache
     n_hit = jnp.int32(0)
-    eval_fn = lambda rows, n: engine.population_counts(problem, rows, n)
-    if mode == "cache" and use_cache and cache is not None:
-        # children duplicating a parent, each other, or ANY chromosome
-        # evaluated earlier in the run reuse cached integer counts
-        counts, n_eval, n_hit, cache = dedup_eval(
-            eval_fn, pop, known=state.counts, axis_name=cfg.batch_axis,
-            gene_mask=problem.genes.valid, cache=cache, gen=state.gen + 1,
-            ids=problem.genes.ids, active=active)
-        c_obj, c_viol = engine.objectives(
-            problem, children, engine.counts_accuracy(problem, counts[P:]))
-    elif mode != "off":
-        # within-generation dedup only (the legacy/oracle path)
-        counts, n_eval = dedup_eval(
-            eval_fn, pop, known=state.counts, axis_name=cfg.batch_axis,
-            gene_mask=problem.genes.valid, ids=problem.genes.ids,
-            active=active)
-        c_obj, c_viol = engine.objectives(
-            problem, children, engine.counts_accuracy(problem, counts[P:]))
+
+    def eval_fn(rows, n):
+        with jax.named_scope("ga.fitness"):
+            return engine.population_counts(problem, rows, n)
+
+    if mode != "off":
+        with jax.named_scope("ga.cache"):
+            if mode == "cache" and use_cache and cache is not None:
+                # children duplicating a parent, each other, or ANY
+                # chromosome evaluated earlier in the run reuse cached
+                # integer counts
+                counts, n_eval, n_hit, cache = dedup_eval(
+                    eval_fn, pop, known=state.counts,
+                    axis_name=cfg.batch_axis,
+                    gene_mask=problem.genes.valid, cache=cache,
+                    gen=state.gen + 1, ids=problem.genes.ids, active=active)
+            else:
+                # within-generation dedup only (the legacy/oracle path)
+                counts, n_eval = dedup_eval(
+                    eval_fn, pop, known=state.counts,
+                    axis_name=cfg.batch_axis,
+                    gene_mask=problem.genes.valid, ids=problem.genes.ids,
+                    active=active)
+        with jax.named_scope("ga.objectives"):
+            c_obj, c_viol = engine.objectives(
+                problem, children,
+                engine.counts_accuracy(problem, counts[P:]))
     else:
         # dedup off: counts are unused placeholders — match the state's
         # count shape, which grows a K column under device-variation MC
         counts = jnp.zeros((2 * P,) + state.counts.shape[1:], jnp.int32)
-        c_obj, c_viol = engine.fitness(problem, children)
+        with jax.named_scope("ga.fitness"):
+            c_obj, c_viol = engine.fitness(problem, children)
         n_eval = (jnp.int32(P) if active is None
                   else jnp.where(active, P, 0).astype(jnp.int32))
     return _rank_and_select(state, pop, counts, c_obj, c_viol, key, cache,

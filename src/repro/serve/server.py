@@ -25,6 +25,7 @@ import json
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from ..core import engine, sweep
 from ..core import genome as genome_mod
@@ -157,50 +158,58 @@ class SearchServer:
                                    generations_budget=jnp.int32(0))
 
     def _admit(self, lane: int, job_id: int):
-        rec = self._jobs[job_id]
-        job = rec.job
-        inner = dataclasses.replace(job.problem, cfg=self._cfg_init)
-        padded = engine.pad_problem(inner, self.spec, self.max_samples)
-        padded = dataclasses.replace(
-            padded, generations_budget=jnp.int32(job.generations))
-        rec.positions = genome_mod.pad_positions(job.problem.spec, self.spec)
-        doping = None
-        if job.doping_seeds is not None:
-            n_dope = max(1, int(self._cfg.doping_frac * self._cfg.pop_size))
-            doping = jnp.asarray(sweep.doped_lane_rows(
-                job.doping_seeds, rec.positions, self.spec.n_genes, n_dope))
-        # the exact init a standalone GATrainer would run on this job
-        state, n0 = _init_lane_jit(padded, jax.random.PRNGKey(job.seed),
-                                   doping)
-        self._problems = _set_lane(
-            self._problems, lane, dataclasses.replace(padded, cfg=self._cfg))
-        self._states = _set_lane(self._states, lane, state)
-        rec.lane = lane
-        rec.remaining = job.generations
-        rec.unique_evals = int(n0)
-        rec.cache_hits = 0
-        rec.admitted_segment = self._segments_done
+        with TraceAnnotation("serve.admit", job=job_id, lane=lane):
+            rec = self._jobs[job_id]
+            job = rec.job
+            inner = dataclasses.replace(job.problem, cfg=self._cfg_init)
+            padded = engine.pad_problem(inner, self.spec, self.max_samples)
+            padded = dataclasses.replace(
+                padded, generations_budget=jnp.int32(job.generations))
+            rec.positions = genome_mod.pad_positions(job.problem.spec,
+                                                     self.spec)
+            doping = None
+            if job.doping_seeds is not None:
+                n_dope = max(1, int(self._cfg.doping_frac
+                                    * self._cfg.pop_size))
+                doping = jnp.asarray(sweep.doped_lane_rows(
+                    job.doping_seeds, rec.positions, self.spec.n_genes,
+                    n_dope))
+            # the exact init a standalone GATrainer would run on this job
+            state, n0 = _init_lane_jit(padded, jax.random.PRNGKey(job.seed),
+                                       doping)
+            self._problems = _set_lane(
+                self._problems, lane,
+                dataclasses.replace(padded, cfg=self._cfg))
+            self._states = _set_lane(self._states, lane, state)
+            rec.lane = lane
+            rec.remaining = job.generations
+            rec.unique_evals = int(n0)
+            rec.cache_hits = 0
+            rec.admitted_segment = self._segments_done
 
     def _retire(self, lane: int, job_id: int, *,
                 converged: bool = False) -> JobResult:
-        rec = self._jobs[job_id]
-        st = engine.state_at(self._states, lane)
-        st = dataclasses.replace(st, pop=st.pop[:, rec.positions], cache=None)
-        result = JobResult(
-            job_id=job_id, name=rec.name, front=engine.front_of(st),
-            state=st, generations=rec.generations,
-            unique_evals=rec.unique_evals, cache_hits=rec.cache_hits,
-            admitted_segment=rec.admitted_segment,
-            retired_segment=self._segments_done,
-            generations_run=rec.generations - max(rec.remaining, 0),
-            converged=converged)
-        # park the lane on the null problem: budget 0 keeps it a no-op
-        # passthrough and its 1-sample bound stops inflating the shared
-        # sample-tile pmax (the lane's stale state is inert garbage)
-        self._problems = _set_lane(self._problems, lane, self._null)
-        rec.lane = None
-        self._sched.free(lane)
-        return result
+        with TraceAnnotation("serve.retire", job=job_id, lane=lane):
+            rec = self._jobs[job_id]
+            st = engine.state_at(self._states, lane)
+            st = dataclasses.replace(st, pop=st.pop[:, rec.positions],
+                                     cache=None)
+            result = JobResult(
+                job_id=job_id, name=rec.name, front=engine.front_of(st),
+                state=st, generations=rec.generations,
+                unique_evals=rec.unique_evals, cache_hits=rec.cache_hits,
+                admitted_segment=rec.admitted_segment,
+                retired_segment=self._segments_done,
+                generations_run=rec.generations - max(rec.remaining, 0),
+                converged=converged)
+            # park the lane on the null problem: budget 0 keeps it a
+            # no-op passthrough and its 1-sample bound stops inflating the
+            # shared sample-tile pmax (the lane's stale state is inert
+            # garbage)
+            self._problems = _set_lane(self._problems, lane, self._null)
+            rec.lane = None
+            self._sched.free(lane)
+            return result
 
     # -- fault-tolerance hooks (driven by serve.supervisor) -----------------
 
@@ -254,30 +263,31 @@ class SearchServer:
                seed: int = 0, doping_seeds=None, name=None) -> int:
         """Enqueue a job; returns its id. Accepts a :class:`SearchJob` or
         a bare Problem plus the job fields as keywords."""
-        if not isinstance(job, SearchJob):
-            if generations is None:
-                generations = job.cfg.generations
-            job = SearchJob(job, generations, seed=seed,
-                            doping_seeds=doping_seeds, name=name)
-        if job.generations < 1:
-            raise ValueError(f"generations must be >= 1, got "
-                             f"{job.generations}")
-        if _canon_cfg(job.problem.cfg) != _canon_cfg(self._cfg):
-            raise ValueError("job problem's GAConfig does not match the "
-                             "server's (one compiled program needs one "
-                             "config; seed/generations ride on the job)")
-        if int(job.problem.x_int.shape[0]) > self.max_samples:
-            raise ValueError(
-                f"job has {job.problem.x_int.shape[0]} samples; the server "
-                f"was sized for max_samples={self.max_samples}")
-        genome_mod.pad_positions(job.problem.spec, self.spec)  # fit check
-        job_id = self._next_id
-        self._next_id += 1
-        self._jobs[job_id] = _JobRecord(
-            job_id=job_id, name=job.name, generations=int(job.generations),
-            seed=int(job.seed), job=job)
-        self._sched.enqueue(job_id)
-        return job_id
+        with TraceAnnotation("serve.enqueue", job=self._next_id):
+            if not isinstance(job, SearchJob):
+                if generations is None:
+                    generations = job.cfg.generations
+                job = SearchJob(job, generations, seed=seed,
+                                doping_seeds=doping_seeds, name=name)
+            if job.generations < 1:
+                raise ValueError(f"generations must be >= 1, got "
+                                 f"{job.generations}")
+            if _canon_cfg(job.problem.cfg) != _canon_cfg(self._cfg):
+                raise ValueError("job problem's GAConfig does not match the "
+                                 "server's (one compiled program needs one "
+                                 "config; seed/generations ride on the job)")
+            if int(job.problem.x_int.shape[0]) > self.max_samples:
+                raise ValueError(
+                    f"job has {job.problem.x_int.shape[0]} samples; the "
+                    f"server was sized for max_samples={self.max_samples}")
+            genome_mod.pad_positions(job.problem.spec, self.spec)  # fit check
+            job_id = self._next_id
+            self._next_id += 1
+            self._jobs[job_id] = _JobRecord(
+                job_id=job_id, name=job.name, generations=int(job.generations),
+                seed=int(job.seed), job=job)
+            self._sched.enqueue(job_id)
+            return job_id
 
     def step(self) -> list[JobResult]:
         """Admit queued jobs into free lanes, run ONE segment, retire
@@ -288,11 +298,13 @@ class SearchServer:
         busy = self._sched.busy_lanes
         if not busy:
             return []
-        self._states, aux = _run_segment_jit(self._problems, self._states,
-                                             self.segment_len)
+        with TraceAnnotation("serve.dispatch", lanes=len(busy)):
+            self._states, aux = _run_segment_jit(
+                self._problems, self._states, self.segment_len)
         self._segments_done += 1
-        n_eval = np.asarray(aux[2])          # (n_lanes, segment_len)
-        n_hit = np.asarray(aux[3])
+        with TraceAnnotation("serve.sync"):
+            n_eval = np.asarray(aux[2])          # (n_lanes, segment_len)
+            n_hit = np.asarray(aux[3])
         retired = []
         for lane in busy:
             rec = self._jobs[self._sched.lane_job[lane]]

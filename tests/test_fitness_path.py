@@ -186,5 +186,13 @@ def test_scan_history_logged(bc_trainers):
     tr = bc_trainers()
     _, hist = tr.run(verbose=True)
     assert [h["gen"] for h in hist] == [0, 7]   # log_every=10, gens=8
-    assert all(set(h) == {"gen", "best_err", "best_area", "time_s"}
+    assert all(set(h) == {"gen", "best_err", "best_area"}
+               for h in hist)
+
+
+def test_stepped_history_logged(bc_trainers):
+    tr = bc_trainers(log_every=3)
+    _, hist = tr.run(verbose=True, scan=False)
+    assert [h["gen"] for h in hist] == [0, 3, 6, 7]
+    assert all(set(h) == {"gen", "best_err", "best_area"}
                for h in hist)
