@@ -342,7 +342,8 @@ def use_dedup(cfg: GAConfig) -> bool:
 
 
 def pad_problem(problem: Problem, spec_pad: GenomeSpec,
-                n_samples: int | None = None) -> Problem:
+                n_samples: int | None = None,
+                pos: np.ndarray | None = None) -> Problem:
     """Embed ``problem`` into the padded max-shape layout of ``spec_pad``.
 
     Returns a Problem that runs bit-identically to the original: genes keep
@@ -354,7 +355,8 @@ def pad_problem(problem: Problem, spec_pad: GenomeSpec,
     the tiled fitness backends *skip* all-padding sample tiles — see
     :func:`population_counts`). ``n_samples`` additionally pads the sample
     axis (features 0, label −1 — never matched by an argmax) so several
-    datasets can stack on a suite axis.
+    datasets can stack on a suite axis. ``pos`` is the gene embedding
+    (``genome.pad_positions``) when the caller already holds it.
 
     The count-based fitness backends handle all of this exactly; the "jnp"
     oracle backend does not (it averages over the padded sample axis), so
@@ -364,7 +366,8 @@ def pad_problem(problem: Problem, spec_pad: GenomeSpec,
         raise ValueError("padded problems need a count-based fitness "
                          "backend (ref/kernel/interpret/auto), not 'jnp'")
     inner = problem.spec
-    pos = genome_mod.pad_positions(inner, spec_pad)
+    if pos is None:
+        pos = genome_mod.pad_positions(inner, spec_pad)
     genes = genome_mod.padded_table(inner, spec_pad, pos)
     x, labels = problem.x_int, problem.labels
     S = x.shape[0]

@@ -14,8 +14,9 @@ compiled program, reused for every segment). Between segments a host-side
 :class:`LaneScheduler` retires lanes whose per-lane generation budget is
 exhausted (returning their Pareto fronts) and admits queued
 :class:`SearchJob`\\ s into the freed slots by padding them into the shared
-max-shape layout at *runtime* — lane composition is a scatter into the
-standing pytrees, not a trace-time constant.
+max-shape layout at *runtime* — lane composition is one compiled write
+at a traced lane index into the standing pytrees, not a trace-time
+constant.
 
 Every job's result is bit-identical to its standalone sequential
 ``GATrainer.run`` (tests/test_serve.py): admission runs the same

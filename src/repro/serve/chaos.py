@@ -170,7 +170,9 @@ class ChaosPlan:
         else:                                        # "counts"
             bad = dataclasses.replace(
                 st, counts=jnp.full_like(st.counts, -1))
-        server._states = server_mod._set_lane(server._states, lane, bad)
+        server._problems, server._states = server_mod._place_lane(
+            server._problems, server._states, np.int32(lane),
+            server.lane_problem(lane), bad)
         return lane
 
     def after_save(self, path: str, step: int):

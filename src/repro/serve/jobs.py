@@ -36,7 +36,8 @@ class JobResult:
     gene layout (like ``SuiteResult.state_at``) and ``unique_evals`` /
     ``cache_hits`` count exactly what that trainer would report. The
     returned state drops the lane's EvalCache (device-resident scratch,
-    not a result).
+    not a result) and holds host (NumPy) arrays: the server reads the
+    lane back in one transfer and builds the front from that copy.
 
     Fault-tolerance fields (PR 10): ``ok`` is False for a *quarantined*
     job — one whose lane tripped ``engine.validate_state`` — in which

@@ -7,8 +7,12 @@ See the package docstring for the architecture. The invariants:
     module-level jit cache is shared across server instances, like
     ``sweep._run_suite_jit``).
   * Lane composition at runtime: admitting a job pads its Problem into
-    the shared max-shape layout (``sweep.pad_lane``) and *scatters* it
-    into the standing stacked Problem — no retrace, no recompile.
+    the shared max-shape layout (``engine.pad_problem``) and writes it and
+    its initial state into the standing stacked pytrees with ONE compiled
+    program (``_place_lane``); retiring a lane peels its state and parks
+    its slot with one more (``_peel_lane``). The lane index is a traced
+    argument, so each program compiles once per stacked shape — no
+    retrace, no recompile, and no per-leaf device programs.
   * Retired lanes are free: the budget gate (``cfg.generations_budget``)
     makes an exhausted lane a bitwise no-op passthrough contributing
     zero rows to the shared dedup evaluation bound; a retired lane's
@@ -20,11 +24,13 @@ See the package docstring for the architecture. The invariants:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.profiler import TraceAnnotation
 
 from ..core import engine, sweep
@@ -65,10 +71,38 @@ def _init_lane(problem: Problem, key, doping):
 _init_lane_jit = jax.jit(_init_lane)
 
 
-def _set_lane(stacked, lane: int, single):
-    """Scatter one lane's pytree into the stacked pytree."""
-    return jax.tree_util.tree_map(lambda s, x: s.at[lane].set(x),
-                                  stacked, single)
+def _take_lane(stacked, lane):
+    """One lane's slice of every leaf of a stacked pytree."""
+    return jax.tree_util.tree_map(
+        lambda s: lax.dynamic_index_in_dim(s, lane, keepdims=False), stacked)
+
+
+def _put_lane(stacked, lane, single):
+    """Write one lane's pytree into every leaf of a stacked pytree."""
+    return jax.tree_util.tree_map(
+        lambda s, x: lax.dynamic_update_index_in_dim(s, x, lane, 0),
+        stacked, single)
+
+
+# ONE dispatch each per admission / retirement, compiled once per stacked
+# shape; the stacked buffers they replace are donated
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _place_lane(problems: Problem, states: GAState, lane, problem: Problem,
+                state: GAState):
+    """Admission: write a padded job Problem and its initial state into
+    lane ``lane`` (a traced int32) of the stacked pytrees."""
+    return _put_lane(problems, lane, problem), _put_lane(states, lane, state)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _peel_lane(problems: Problem, states: GAState, lane, null: Problem):
+    """Retirement: lane ``lane``'s state without its EvalCache, and the
+    stacked problems with that lane parked on ``null``."""
+    state = _take_lane(dataclasses.replace(states, cache=None), lane)
+    return state, _put_lane(problems, lane, null)
+
+
+_lane_of = jax.jit(_take_lane)
 
 
 @dataclasses.dataclass
@@ -83,7 +117,9 @@ class _JobRecord:
     lane: int | None = None
     positions: np.ndarray | None = None   # inner→padded gene positions
     remaining: int = 0
-    unique_evals: int = 0
+    # from admission to the step's serve.sync read, the init's device
+    # scalar; a host int after it
+    unique_evals: int | jax.Array = 0
     cache_hits: int = 0
     admitted_segment: int | None = None
 
@@ -162,11 +198,10 @@ class SearchServer:
             rec = self._jobs[job_id]
             job = rec.job
             inner = dataclasses.replace(job.problem, cfg=self._cfg_init)
-            padded = engine.pad_problem(inner, self.spec, self.max_samples)
+            padded = engine.pad_problem(inner, self.spec, self.max_samples,
+                                        pos=rec.positions)
             padded = dataclasses.replace(
                 padded, generations_budget=jnp.int32(job.generations))
-            rec.positions = genome_mod.pad_positions(job.problem.spec,
-                                                     self.spec)
             doping = None
             if job.doping_seeds is not None:
                 n_dope = max(1, int(self._cfg.doping_frac
@@ -177,36 +212,38 @@ class SearchServer:
             # the exact init a standalone GATrainer would run on this job
             state, n0 = _init_lane_jit(padded, jax.random.PRNGKey(job.seed),
                                        doping)
-            self._problems = _set_lane(
-                self._problems, lane,
-                dataclasses.replace(padded, cfg=self._cfg))
-            self._states = _set_lane(self._states, lane, state)
+            self._problems, self._states = _place_lane(
+                self._problems, self._states, np.int32(lane),
+                dataclasses.replace(padded, cfg=self._cfg), state)
             rec.lane = lane
             rec.remaining = job.generations
-            rec.unique_evals = int(n0)
+            rec.unique_evals = n0      # read with the segment's accounting
             rec.cache_hits = 0
             rec.admitted_segment = self._segments_done
 
-    def _retire(self, lane: int, job_id: int, *,
-                converged: bool = False) -> JobResult:
+    def _retire(self, lane: int, job_id: int, *, converged: bool = False,
+                error: str | None = None) -> JobResult:
+        """Peel the lane's state to the host, gathered back to the job's
+        gene layout, and park the slot on the null problem: budget 0
+        keeps it a no-op passthrough and its 1-sample bound stops
+        inflating the shared sample-tile pmax (the lane's stale state is
+        inert garbage). With ``error`` the job is retired as failed."""
         with TraceAnnotation("serve.retire", job=job_id, lane=lane):
             rec = self._jobs[job_id]
-            st = engine.state_at(self._states, lane)
-            st = dataclasses.replace(st, pop=st.pop[:, rec.positions],
-                                     cache=None)
+            st, self._problems = _peel_lane(
+                self._problems, self._states, np.int32(lane), self._null)
+            st = jax.device_get(st)
+            st = dataclasses.replace(st, pop=st.pop[:, rec.positions])
             result = JobResult(
-                job_id=job_id, name=rec.name, front=engine.front_of(st),
+                job_id=job_id, name=rec.name,
+                front=None if error is not None else engine.front_of(st),
                 state=st, generations=rec.generations,
                 unique_evals=rec.unique_evals, cache_hits=rec.cache_hits,
                 admitted_segment=rec.admitted_segment,
                 retired_segment=self._segments_done,
+                ok=error is None, error=error,
                 generations_run=rec.generations - max(rec.remaining, 0),
                 converged=converged)
-            # park the lane on the null problem: budget 0 keeps it a
-            # no-op passthrough and its 1-sample bound stops inflating the
-            # shared sample-tile pmax (the lane's stale state is inert
-            # garbage)
-            self._problems = _set_lane(self._problems, lane, self._null)
             rec.lane = None
             self._sched.free(lane)
             return result
@@ -234,28 +271,15 @@ class SearchServer:
         job_id = self._sched.lane_job[lane]
         if job_id is None:
             raise ValueError(f"lane {lane} has no job to quarantine")
-        rec = self._jobs[job_id]
-        st = engine.state_at(self._states, lane)
-        st = dataclasses.replace(st, pop=st.pop[:, rec.positions], cache=None)
-        result = JobResult(
-            job_id=job_id, name=rec.name, front=None, state=st,
-            generations=rec.generations, unique_evals=rec.unique_evals,
-            cache_hits=rec.cache_hits,
-            admitted_segment=rec.admitted_segment,
-            retired_segment=self._segments_done, ok=False, error=error,
-            generations_run=rec.generations - max(rec.remaining, 0))
-        self._problems = _set_lane(self._problems, lane, self._null)
-        rec.lane = None
-        self._sched.free(lane)
-        return result
+        return self._retire(lane, job_id, error=error)
 
     def lane_state(self, lane: int) -> GAState:
         """The full padded GAState of one lane (cache included) — the
         view ``engine.validate_state`` checks at segment boundaries."""
-        return engine.state_at(self._states, lane)
+        return _lane_of(self._states, np.int32(lane))
 
     def lane_problem(self, lane: int) -> Problem:
-        return jax.tree_util.tree_map(lambda x: x[lane], self._problems)
+        return _lane_of(self._problems, np.int32(lane))
 
     # -- the service loop ---------------------------------------------------
 
@@ -280,12 +304,13 @@ class SearchServer:
                 raise ValueError(
                     f"job has {job.problem.x_int.shape[0]} samples; the "
                     f"server was sized for max_samples={self.max_samples}")
-            genome_mod.pad_positions(job.problem.spec, self.spec)  # fit check
+            # raises if the job's topology does not fit the server's spec
+            positions = genome_mod.pad_positions(job.problem.spec, self.spec)
             job_id = self._next_id
             self._next_id += 1
             self._jobs[job_id] = _JobRecord(
                 job_id=job_id, name=job.name, generations=int(job.generations),
-                seed=int(job.seed), job=job)
+                seed=int(job.seed), job=job, positions=positions)
             self._sched.enqueue(job_id)
             return job_id
 
@@ -302,13 +327,15 @@ class SearchServer:
             self._states, aux = _run_segment_jit(
                 self._problems, self._states, self.segment_len)
         self._segments_done += 1
+        recs = [self._jobs[self._sched.lane_job[lane]] for lane in busy]
         with TraceAnnotation("serve.sync"):
-            n_eval = np.asarray(aux[2])          # (n_lanes, segment_len)
-            n_hit = np.asarray(aux[3])
+            # (n_lanes, segment_len) accounting and the admissions' init
+            # evals, in one read
+            n_eval, n_hit, evals = jax.device_get(
+                (aux[2], aux[3], [rec.unique_evals for rec in recs]))
         retired = []
-        for lane in busy:
-            rec = self._jobs[self._sched.lane_job[lane]]
-            rec.unique_evals += int(n_eval[lane].sum())
+        for lane, rec, ev in zip(busy, recs, evals):
+            rec.unique_evals = int(ev) + int(n_eval[lane].sum())
             rec.cache_hits += int(n_hit[lane].sum())
             rec.remaining -= self.segment_len
             if rec.remaining <= 0:

@@ -21,6 +21,7 @@ from repro.core import engine
 from repro.core.genome import MLPTopology
 from repro.data import load_dataset
 from repro.serve import LaneScheduler, SearchJob, SearchServer
+from repro.serve import server as server_mod
 
 STATE_FIELDS = ("pop", "obj", "viol", "rank", "crowd", "counts", "key", "gen")
 
@@ -257,6 +258,59 @@ def test_retired_lanes_leave_survivors_clean(two_datasets):
     tr, state = _trainer_state(rw, cfg, 1, 6)
     assert_states_equal(survivor, state, "survivor lane")
     assert results[long_].unique_evals == tr.unique_evals
+
+
+def test_lane_programs_compile_once(two_datasets):
+    """Admissions into and retirements from all four lanes — two
+    topologies, lanes reused, one quarantine — compile the place and peel
+    programs once each: the lane is a traced index, not a static."""
+    bc, rw = two_datasets
+    cfg = GAConfig(pop_size=16, generations=4)
+    pa, pb = _problem(bc, cfg), _problem(rw, cfg)
+    srv = SearchServer.for_problems([pa, pb], n_lanes=4, segment_len=2)
+    server_mod._place_lane.clear_cache()
+    server_mod._peel_lane.clear_cache()
+    budgets = [(pa, 2), (pb, 4), (pa, 6), (pb, 2), (pa, 2), (pb, 4)]
+    ids = [srv.submit(p, generations=g, seed=i)
+           for i, (p, g) in enumerate(budgets)]
+    first = srv.step()
+    # four jobs admitted at once: two retired, two still in their lanes
+    assert sorted(r.job_id for r in first) == [ids[0], ids[3]]
+    assert sorted(srv.active_jobs) == [1, 2]
+    failed = srv.quarantine_lane(2, "probe")
+    rest = srv.drain()
+    assert not failed.ok and failed.job_id == ids[2]
+    assert sorted(r.job_id for r in first + rest + [failed]) == ids
+    assert server_mod._place_lane._cache_size() == 1
+    assert server_mod._peel_lane._cache_size() == 1
+
+
+@pytest.mark.parametrize("dedup", [True, False])
+def test_lane_reused_after_retire_and_quarantine(two_datasets, dedup):
+    """One lane serves a job to its end, then a job quarantined mid-budget,
+    then a third job: the first and third equal their standalone
+    trainers — states, fronts and eval accounting."""
+    bc, rw = two_datasets
+    cfg = GAConfig(pop_size=16, generations=4, dedup=dedup)
+    pa, pb = _problem(bc, cfg), _problem(rw, cfg)
+    srv = SearchServer.for_problems([pa, pb], n_lanes=1, segment_len=2)
+    srv.submit(pb, generations=2, seed=3)
+    [done] = srv.step()
+    srv.submit(pa, generations=6, seed=4)
+    assert srv.step() == []
+    failed = srv.quarantine_lane(0, "probe")
+    assert not failed.ok and failed.front is None
+    assert failed.error == "probe" and failed.generations_run == 2
+    srv.submit(pa, generations=4, seed=5)
+    [got] = srv.drain()
+    for r, ds, seed, gens in ((done, rw, 3, 2), (got, bc, 5, 4)):
+        tr, state = _trainer_state(ds, cfg, seed, gens)
+        assert_states_equal(r.state, state, f"job {r.job_id}")
+        assert r.unique_evals == tr.unique_evals, f"job {r.job_id}"
+        assert r.cache_hits == tr.cache_hits, f"job {r.job_id}"
+        front = tr.front(state)
+        for k in ("objectives", "genomes"):
+            np.testing.assert_array_equal(r.front[k], front[k])
 
 
 def test_submit_validation(two_datasets):
