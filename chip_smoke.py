@@ -46,7 +46,8 @@ GENERATIONS = 8
 # the EvalCache leaf is left out: the megakernel passes it through untouched
 STATE_FIELDS = ("pop", "obj", "viol", "rank", "crowd", "counts", "key", "gen")
 # the jnp paths every kernel is checked against
-JNP = BackendPolicy(fitness="ref", variation="ref", generation="ref")
+JNP = BackendPolicy(fitness="ref", variation="ref", generation="ref",
+                    ranking="sweep")
 # (dataset, generations, seed) of the service phase's job stream
 JOBS = (("cardio", 4, 0), ("pendigits", 12, 1), ("cardio", 8, 2),
         ("pendigits", 4, 3), ("cardio", 12, 4), ("pendigits", 8, 5))

@@ -88,8 +88,9 @@ class GAConfig:
     # ``backends`` is THE knob: one validated BackendPolicy naming a
     # backend per dispatch path (fitness auto|kernel|interpret|ref|jnp,
     # variation auto|kernel|interpret|ref|ops, generation
-    # auto|kernel|interpret|ref|phases, ranking auto|sweep|matrix — every
-    # non-oracle choice bit-identical, see repro.kernels). The four
+    # auto|kernel|interpret|ref|phases, ranking
+    # auto|kernel|interpret|sweep|matrix — every non-oracle choice
+    # bit-identical, see repro.kernels). The four
     # ``*_backend`` fields below are DEPRECATED aliases: a non-None value
     # overrides the matching policy field (and warns once), and after
     # construction they always mirror the resolved policy, so legacy

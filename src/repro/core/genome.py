@@ -346,6 +346,15 @@ class GenomeSpec:
         self.gene_ids = jnp.arange(off, dtype=jnp.int32)
         self.gene_valid = jnp.ones(off, bool)
 
+    # Everything above follows from the topology, so two specs of one
+    # topology are interchangeable: a program keyed on a spec (a static
+    # part of every Problem) is reused for a fresh spec of the same shape.
+    def __eq__(self, other):
+        return isinstance(other, GenomeSpec) and other.topo == self.topo
+
+    def __hash__(self):
+        return hash(self.topo)
+
     def table(self) -> GeneTable:
         """The spec's own GeneTable (identity layout: ids are positions,
         every gene valid)."""

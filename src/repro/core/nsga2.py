@@ -15,8 +15,9 @@ violation vector ``viol`` (0 = feasible).
 
 The dominance-matrix + front-peel pair here is the O(P²) *oracle* path of
 the ``repro.kernels.pop_ranking`` dispatcher
-(``GAConfig.ranking_backend="matrix"``); the default "sweep" backend
-computes identical ranks in O(P log P) fixed-shape sorts and scans.
+(``GAConfig.ranking_backend="matrix"``); the default sweep computes
+identical ranks in O(P log P) fixed-shape sorts and a staircase pass
+(a ``lax.scan``, or one Pallas call on TPU).
 Crowding, tournament and survivor selection are shared by both backends.
 """
 from __future__ import annotations

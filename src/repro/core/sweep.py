@@ -45,10 +45,12 @@ handful of shared-program dispatches (``benchmarks/common.ga_run_suite``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import AxisType, Mesh, PartitionSpec as P
 from jax import shard_map
 
@@ -126,33 +128,38 @@ def _auto_axes(mesh: Mesh) -> Mesh:
                 axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
-def _run_cells_sharded(problem: Problem, seeds, pcs, pms, mals, baccs,
-                       doping, generations: int, mesh: Mesh,
-                       axis_names: tuple[str, ...]):
-    """shard_map the cell axis over ``mesh``: each device vmaps its slice
-    of cells with the data replicated. Cells are padded (by repeating the
-    last cell) to a multiple of the device count and the pads dropped —
-    per-cell results are independent, so this is bit-identical to the
-    unsharded path."""
-    n = seeds.shape[0]
+@functools.lru_cache(maxsize=None)
+def _sharded_program(fn, generations: int, mesh: Mesh,
+                     axis_names: tuple[str, ...], replicated: tuple):
+    """``jit(shard_map(fn))`` over ``mesh``, splitting the leading cell
+    axis of every argument not flagged in ``replicated``. Built once per
+    key and reused: a new jitted function would trace and lower its
+    program again on every call."""
+    pspec = P(axis_names)
+    return jax.jit(shard_map(
+        functools.partial(fn, generations=generations),
+        mesh=_auto_axes(mesh),
+        in_specs=tuple(P() if r else pspec for r in replicated),
+        out_specs=pspec, check_vma=False))
+
+
+def _shard_cells(fn, generations: int, mesh: Mesh,
+                 axis_names: tuple[str, ...], args, replicated: tuple):
+    """Run ``fn``'s cells split over ``mesh``. Cells are padded (by
+    repeating the last cell) to a multiple of the device count and the
+    pads dropped — per-cell results are independent, so this is
+    bit-identical to the unsharded path."""
+    n = jax.tree_util.tree_leaves(
+        [a for a, r in zip(args, replicated) if not r])[0].shape[0]
     n_dev = int(np.prod([mesh.shape[a] for a in axis_names]))
     pad = (-n) % n_dev
     if pad:
         def padded(a):
             return jnp.concatenate([a, jnp.repeat(a[-1:], pad, axis=0)])
-        seeds, pcs, pms, mals, baccs = map(
-            padded, (seeds, pcs, pms, mals, baccs))
-
-    pspec = P(axis_names)
-    fn = jax.jit(shard_map(
-        lambda p, s, a, b, c, d, e: _run_cells(p, s, a, b, c, d, e,
-                                               generations),
-        mesh=_auto_axes(mesh),
-        in_specs=(P(), pspec, pspec, pspec, pspec, pspec, P()),
-        out_specs=pspec,
-        check_vma=False,
-    ))
-    out = fn(problem, seeds, pcs, pms, mals, baccs, doping)
+        args = [a if r else jax.tree_util.tree_map(padded, a)
+                for a, r in zip(args, replicated)]
+    out = _sharded_program(fn, generations, mesh, tuple(axis_names),
+                           replicated)(*args)
     if pad:
         out = jax.tree_util.tree_map(lambda x: x[:n], out)
     return out
@@ -252,8 +259,10 @@ def run_grid(problem: Problem, seeds, *, crossover_rates=None,
             jnp.asarray(cells["max_acc_loss"]),
             jnp.asarray(cells["baseline_acc"]))
     if mesh is not None:
-        states, aux, n0 = _run_cells_sharded(problem, *args, doping, gens,
-                                             mesh, axis_names)
+        # data replicated, cells split
+        states, aux, n0 = _shard_cells(
+            _run_cells, gens, mesh, axis_names, (problem, *args, doping),
+            (True, False, False, False, False, False, True))
     else:
         fn = _run_cells_jit if jit else _run_cells
         states, aux, n0 = fn(problem, *args, doping, gens)
@@ -322,31 +331,28 @@ def _run_suite_cells(problem: Problem, seeds, doping, generations: int):
 _run_suite_jit = jax.jit(_run_suite_cells, static_argnames="generations")
 
 
-def _run_suite_sharded(problem: Problem, seeds, doping, generations: int,
-                       mesh: Mesh, axis_names: tuple[str, ...]):
-    """shard_map the suite-cell axis over ``mesh`` (cells split, nothing
-    replicated — every leaf is per-cell). Cells are padded to a multiple of
-    the device count by repeating the last cell and the pads dropped;
-    per-cell results are independent, so this is bit-identical to vmap."""
-    n = seeds.shape[0]
-    n_dev = int(np.prod([mesh.shape[a] for a in axis_names]))
-    pad = (-n) % n_dev
-    if pad:
-        def padded(a):
-            return jnp.concatenate([a, jnp.repeat(a[-1:], pad, axis=0)])
-        problem, seeds, doping = jax.tree_util.tree_map(
-            padded, (problem, seeds, doping))
+@jax.jit
+def _bucket_lanes(lanes, crossover_rates, mutation_rates, max_acc_losses,
+                  baseline_accs) -> Problem:
+    """A bucket's stacked cell Problems in one program: each padded
+    dataset lane in ``lanes`` repeated for its grid's cells, the cells'
+    hyperparameters (flat, dataset-major) set in place. The same arrays
+    as ``stack_problems`` over ``with_hypers`` copies, without one eager
+    device operation per cell and leaf."""
+    reps = crossover_rates.shape[0] // len(lanes)
+    stacked = jax.tree_util.tree_map(
+        lambda *xs: jnp.concatenate(
+            [jnp.broadcast_to(x, (reps,) + x.shape) for x in xs]), *lanes)
+    return stacked.with_hypers(crossover_rates, mutation_rates,
+                               max_acc_losses, baseline_accs)
 
-    pspec = P(axis_names)
-    fn = jax.jit(shard_map(
-        lambda p, s, d: _run_suite_cells(p, s, d, generations),
-        mesh=_auto_axes(mesh), in_specs=(pspec, pspec, pspec), out_specs=pspec,
-        check_vma=False,
-    ))
-    out = fn(problem, seeds, doping)
-    if pad:
-        out = jax.tree_util.tree_map(lambda x: x[:n], out)
-    return out
+
+@jax.jit
+def _dataset_order(outs, order):
+    """The buckets' outputs as one batch, cells in dataset order: row j of
+    the result is row ``order[j]`` of the buckets' outputs concatenated."""
+    return jax.tree_util.tree_map(
+        lambda *xs: jnp.take(jnp.concatenate(xs), order, axis=0), *outs)
 
 
 @dataclasses.dataclass
@@ -367,6 +373,7 @@ class SuiteResult:
     states: GAState
     aux: tuple                  # (best_err, best_area, n_eval, n_hit)
     init_evals: jnp.ndarray     # (n_cells,) unique rows of the init scoring
+    buckets: list               # per dispatch: (datasets, sample bound)
 
     @property
     def shape(self) -> tuple:
@@ -405,7 +412,16 @@ class SuiteResult:
         """Feasible Pareto front of cell ``i``; genomes in the dataset's
         unpadded layout (objectives/violations are bit-identical either
         way — padding contributes zero area and zero logits)."""
-        return engine.front_of(self.state_at(i))
+        state = engine.state_at(self._host_states, i)
+        pos = self.positions[self.dataset_of(i)]
+        return engine.front_of(dataclasses.replace(state,
+                                                   pop=state.pop[:, pos]))
+
+    @functools.cached_property
+    def _host_states(self):
+        """Every cell's state on the host, its cache left out, in one read:
+        a front then costs no device operation."""
+        return jax.device_get(dataclasses.replace(self.states, cache=None))
 
     def unique_evals(self, i: int) -> int:
         """Unique chromosome rows cell ``i`` actually evaluated — matches
@@ -418,6 +434,34 @@ class SuiteResult:
         """Evaluations cell ``i`` reused from its cross-generation cache —
         matches the unpadded sequential ``GATrainer.cache_hits``."""
         return int(np.asarray(self.aux[3][i]).sum())
+
+    def fitness_terms(self) -> tuple[int, int]:
+        """(real, run) shift-add terms of the suite's fitness, each over
+        the chromosome rows the cells really evaluated (``unique_evals``)
+        and every device instance. Real: a cell's own training rows × its
+        own Σ fan_in·fan_out. Run: its bucket's sample bound in whole
+        sample tiles of the Pallas kernels × Σ fan_in·fan_out of the
+        padded spec, as its lane computed them. 1 − real/run is the share
+        of the fitness work that falls on padding."""
+        def weights(spec):
+            s = spec.topo.sizes
+            return sum(a * b for a, b in zip(s, s[1:]))
+
+        cfg = self.problems[0].cfg
+        tile = min(cfg.sample_tile, 128)
+        k = cfg.n_device_samples if engine.variation_on(cfg) else 1
+        evals = (np.asarray(self.init_evals, np.int64)
+                 + np.asarray(self.aux[2], np.int64).sum(axis=1))
+        per_dataset = np.bincount(self.cells["dataset"], weights=evals,
+                                  minlength=len(self.problems))
+        real = run = 0
+        for datasets, bound in self.buckets:
+            run_rows = -(-bound // tile) * tile
+            for d in datasets:
+                p, n = self.problems[d], int(per_dataset[d]) * k
+                real += n * int(p.x_int.shape[0]) * weights(p.spec)
+                run += n * run_rows * weights(self.spec)
+        return real, run
 
 
 def _sample_buckets(sizes, factor):
@@ -517,49 +561,47 @@ def run_suite(problems, seeds, *, crossover_rates=None, mutation_rates=None,
     # widest dataset. The gene axis is shared too, so per-cell outputs of
     # all buckets have identical shapes and concatenate into dataset order.
     s_max = max(sizes)
-    per_dataset, meta, grid_shape = {}, {}, None
+    # each dataset's (seed × config) grid; padding keeps the hyper leaves
+    grids = [grid_cells(seeds, crossover_rates, mutation_rates,
+                        max_acc_losses, baseline_accs, problem=p)
+             for p in problems]
+    grid_shape = grids[0]["shape"]
+    n_grid = int(np.prod(grid_shape))
+    outs = []
     for bucket in buckets:
-        cell_problems, cell_dope, n_grid = [], [], None
-        for d in bucket:
-            p = pad_lane(problems[d], spec_pad, s_max)
-            cells_d = grid_cells(seeds, crossover_rates, mutation_rates,
-                                 max_acc_losses, baseline_accs, problem=p)
+        # a bucket's span covers its lanes' composition and its dispatch
+        with TraceAnnotation("suite.bucket", cells=len(bucket) * n_grid,
+                             datasets=",".join(str(names[d])
+                                               for d in bucket)):
+            lanes = [pad_lane(problems[d], spec_pad, s_max) for d in bucket]
+            hypers = [np.concatenate([grids[d][k] for d in bucket])
+                      for k in ("crossover_rate", "mutation_rate_gene",
+                                "max_acc_loss", "baseline_acc")]
+            stacked = _bucket_lanes(lanes, *hypers)
+            seed_arr = np.concatenate([grids[d]["seed"] for d in bucket])
+            doping = None
             if doping_seeds is not None:
-                dope_rows = doped_lane_rows(doping_seeds[d], positions[d],
-                                            spec_pad.n_genes, n_dope)
-            for k in range(cells_d["seed"].shape[0]):
-                cell_problems.append(p.with_hypers(
-                    jnp.float32(cells_d["crossover_rate"][k]),
-                    jnp.float32(cells_d["mutation_rate_gene"][k]),
-                    jnp.float32(cells_d["max_acc_loss"][k]),
-                    jnp.float32(cells_d["baseline_acc"][k])))
-                if doping_seeds is not None:
-                    cell_dope.append(dope_rows)
-            meta[d] = [(d, cells_d["seed"][k],
-                        cells_d["crossover_rate"][k],
-                        cells_d["mutation_rate_gene"][k],
-                        cells_d["max_acc_loss"][k],
-                        cells_d["baseline_acc"][k])
-                       for k in range(cells_d["seed"].shape[0])]
-            n_grid = cells_d["seed"].shape[0]
-            grid_shape = cells_d["shape"]
+                doping = np.concatenate([np.broadcast_to(
+                    doped_lane_rows(doping_seeds[d], positions[d],
+                                    spec_pad.n_genes, n_dope),
+                    (n_grid, n_dope, spec_pad.n_genes)) for d in bucket])
+            if mesh is not None:
+                out = _shard_cells(_run_suite_cells, gens, mesh,
+                                   axis_names, (stacked, seed_arr, doping),
+                                   (False, False, False))
+            else:
+                fn = _run_suite_jit if jit else _run_suite_cells
+                out = fn(stacked, seed_arr, doping, gens)
+            outs.append(out)
 
-        stacked = stack_problems(cell_problems)
-        seed_arr = jnp.asarray(np.concatenate(
-            [[m[1] for m in meta[d]] for d in bucket]).astype(np.int32))
-        doping = (None if doping_seeds is None
-                  else jnp.asarray(np.stack(cell_dope)))
-        if mesh is not None:
-            out = _run_suite_sharded(stacked, seed_arr, doping, gens,
-                                     mesh, axis_names)
-        else:
-            fn = _run_suite_jit if jit else _run_suite_cells
-            out = fn(stacked, seed_arr, doping, gens)
-        for j, d in enumerate(bucket):
-            sl = slice(j * n_grid, (j + 1) * n_grid)
-            per_dataset[d] = jax.tree_util.tree_map(lambda x: x[sl], out)
-
-    flat = [m for d in range(len(problems)) for m in meta[d]]
+    # row of each (dataset, grid cell) in the buckets' concatenated outputs
+    ran_order = [d for b in buckets for d in b]
+    order = np.concatenate([ran_order.index(d) * n_grid + np.arange(n_grid)
+                            for d in range(len(problems))]).astype(np.int32)
+    flat = [(d, *(grids[d][k][i] for k in ("seed", "crossover_rate",
+                                           "mutation_rate_gene",
+                                           "max_acc_loss", "baseline_acc")))
+            for d in range(len(problems)) for i in range(n_grid)]
     cells = {"dataset": np.asarray([m[0] for m in flat], np.int32),
              "seed": np.asarray([m[1] for m in flat], np.int32),
              "crossover_rate": np.asarray([m[2] for m in flat], np.float32),
@@ -568,8 +610,9 @@ def run_suite(problems, seeds, *, crossover_rates=None, mutation_rates=None,
              "max_acc_loss": np.asarray([m[4] for m in flat], np.float32),
              "baseline_acc": np.asarray([m[5] for m in flat], np.float32),
              "shape": (len(problems),) + grid_shape}
-    states, aux, n0 = jax.tree_util.tree_map(
-        lambda *xs: jnp.concatenate(xs),
-        *[per_dataset[d] for d in range(len(problems))])
+    with TraceAnnotation("suite.gather"):
+        states, aux, n0 = _dataset_order(outs, order)
+    # each bucket's datasets and the sample bound its lanes ran at
+    ran = [(tuple(b), max(sizes[d] for d in b)) for b in buckets]
     return SuiteResult(problems, spec_pad, names, positions, cells, states,
-                       aux, n0)
+                       aux, n0, ran)

@@ -11,7 +11,7 @@ BACKEND_CHOICES = {
     "fitness": ("auto", "kernel", "interpret", "ref", "jnp"),
     "variation": ("auto", "kernel", "interpret", "ref", "ops"),
     "generation": ("auto", "kernel", "interpret", "ref", "phases"),
-    "ranking": ("auto", "sweep", "matrix"),
+    "ranking": ("auto", "kernel", "interpret", "sweep", "matrix"),
 }
 
 

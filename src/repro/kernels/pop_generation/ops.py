@@ -42,9 +42,9 @@ BACKENDS = BACKEND_CHOICES["generation"]
 
 def _generation_kernel(problem, state, interpret: bool, active=None):
     """Megakernel path: parent gather in XLA, variation+fitness fused in
-    one pallas_call, ranking in XLA (through the ``pop_ranking``
-    dispatcher, honouring ``GAConfig.ranking_backend``) — all inside the
-    caller's jit."""
+    one pallas_call, ranking through the ``pop_ranking`` dispatcher
+    (honouring ``GAConfig.ranking_backend``) — all inside the caller's
+    jit."""
     from ...core import engine  # lazy: engine dispatches back into us
 
     cfg = problem.cfg

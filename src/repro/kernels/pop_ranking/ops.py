@@ -7,14 +7,18 @@ crowding / survivor computation in the engine routes through here,
 selected by ``GAConfig.ranking_backend``.
 
 Backends:
-  "auto"   — the O(P log P) sort-and-sweep (fixed-shape; the default
-             everywhere — ranking has no TPU-vs-CPU split)
-  "sweep"  — the sweep, explicitly (``pop_ranking.sweep``)
-  "matrix" — the O(P²) dominance-matrix + bounded front-peel oracle of
-             ``repro.core.nsga2`` (seed semantics, kept as the
-             equivalence reference)
+  "auto"      — the sweep with its staircase pass as a Pallas kernel on
+                TPU, the sweep's ``lax.scan`` elsewhere (default)
+  "kernel"    — the sweep, staircase pass compiled as one Pallas call
+                (``pop_ranking.kernel``)
+  "interpret" — the same kernel in interpret mode (validation on CPU)
+  "sweep"     — the O(P log P) sort-and-sweep with its ``lax.scan``
+                (``pop_ranking.sweep``)
+  "matrix"    — the O(P²) dominance-matrix + bounded front-peel oracle of
+                ``repro.core.nsga2`` (seed semantics, kept as the
+                equivalence reference)
 
-Both backends produce bit-identical results — the front index of an
+All backends produce bit-identical results — the front index of an
 individual is a well-defined integer, the sweep computes the same
 integers without materialising the O(P²) matrix or running the
 data-dependent peel loop, and crowding/survivor selection are shared
@@ -26,6 +30,7 @@ matrix (``nsga2.subset_ranking``), while the sweep simply re-sweeps the
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ...core.nsga2 import (crowding_distance, dominance_matrix,
@@ -39,11 +44,15 @@ BACKENDS = BACKEND_CHOICES["ranking"]
 
 def _resolve(backend: str | None) -> str:
     if backend is None or backend == "auto":
-        return "sweep"
+        return "kernel" if jax.default_backend() == "tpu" else "sweep"
     if backend not in BACKENDS:
         raise ValueError(f"unknown ranking backend {backend!r}; "
                          f"want {BACKENDS}")
     return backend
+
+
+def _sweep_args(backend: str) -> dict:
+    return {"kernel": backend != "sweep", "interpret": backend == "interpret"}
 
 
 def fold_objectives(obj):
@@ -89,8 +98,9 @@ def population_ranking(obj, viol, *, backend: str | None = None):
     A third objective column (robust error, device-variation MC fitness)
     is folded lexicographically first — see :func:`fold_objectives`."""
     obj = fold_objectives(obj)
-    if _resolve(backend) == "sweep":
-        return sweep_ranking(obj, viol)
+    backend = _resolve(backend)
+    if backend != "matrix":
+        return sweep_ranking(obj, viol, **_sweep_args(backend))
     return evaluate_ranking(obj, viol)
 
 
@@ -107,10 +117,11 @@ def rank_select_rerank(obj, viol, mu: int, *, backend: str | None = None):
     on both backends alike.
     """
     obj = fold_objectives(obj)
-    if _resolve(backend) == "sweep":
-        rank, crowd = sweep_ranking(obj, viol)
+    backend = _resolve(backend)
+    if backend != "matrix":
+        rank, crowd = sweep_ranking(obj, viol, **_sweep_args(backend))
         keep = survivor_select(rank, crowd, mu)
-        rank2 = sweep_rank(obj[keep], viol[keep])
+        rank2 = sweep_rank(obj[keep], viol[keep], **_sweep_args(backend))
         return keep, rank2, crowding_distance(obj[keep], rank2)
     dom = dominance_matrix(obj, viol)
     rank, crowd = ranking_from_dom(dom, obj)

@@ -88,12 +88,16 @@ def _sort_and_key(obj: jnp.ndarray, viol: jnp.ndarray):
     return k1s, fs, key, order
 
 
-def sweep_rank(obj: jnp.ndarray, viol: jnp.ndarray) -> jnp.ndarray:
+def sweep_rank(obj: jnp.ndarray, viol: jnp.ndarray, *,
+               kernel: bool = False, interpret: bool = False) -> jnp.ndarray:
     """Constrained non-dominated front index per individual (0 = best).
 
     obj: (P, 2) to-minimize objectives; viol: (P,) violation (≤ 0 means
     feasible). Returns (P,) int32 ranks equal to
     ``nsga2.nondominated_rank(nsga2.dominance_matrix(obj, viol))``.
+    ``kernel`` runs the staircase pass as one Pallas call
+    (``pop_ranking.kernel``; ``interpret`` for its interpret mode) instead
+    of the ``lax.scan``; the ranks are the same.
     """
     P, M = obj.shape
     if M != 2:
@@ -106,8 +110,14 @@ def sweep_rank(obj: jnp.ndarray, viol: jnp.ndarray) -> jnp.ndarray:
         staircase = jnp.where(f, staircase.at[r].min(k), staircase)
         return staircase, r
 
-    m0 = jnp.full((P,), _IMAX)
-    _, ranks_f = jax.lax.scan(step, m0, (key, fs), unroll=16)
+    if kernel:
+        from .kernel import staircase_fronts
+
+        ranks_f = staircase_fronts(jnp.where(fs, key, _IMAX),
+                                   interpret=interpret)
+    else:
+        m0 = jnp.full((P,), _IMAX)
+        _, ranks_f = jax.lax.scan(step, m0, (key, fs), unroll=16)
 
     # infeasible layers start after the last feasible front
     prev_k1 = jnp.concatenate([k1s[:1], k1s[:-1]])
@@ -120,11 +130,12 @@ def sweep_rank(obj: jnp.ndarray, viol: jnp.ndarray) -> jnp.ndarray:
     return jnp.zeros((P,), jnp.int32).at[order].set(rank_s)
 
 
-def sweep_ranking(obj: jnp.ndarray, viol: jnp.ndarray):
+def sweep_ranking(obj: jnp.ndarray, viol: jnp.ndarray, *,
+                  kernel: bool = False, interpret: bool = False):
     """(rank, crowd) via the sweep — the fast-path twin of
     ``nsga2.evaluate_ranking`` (crowding is shared: identical ranks give
     identical distances)."""
     from ...core.nsga2 import crowding_distance
 
-    rank = sweep_rank(obj, viol)
+    rank = sweep_rank(obj, viol, kernel=kernel, interpret=interpret)
     return rank, crowding_distance(obj, rank)

@@ -32,13 +32,23 @@ def assert_states_equal(a, b, msg=""):
 # -- dispatcher --------------------------------------------------------------
 
 def test_backend_list_is_closed():
-    assert BACKENDS == ("auto", "sweep", "matrix")
+    assert BACKENDS == ("auto", "kernel", "interpret", "sweep", "matrix")
     obj = jnp.zeros((4, 2))
     viol = jnp.zeros((4,))
     with pytest.raises(ValueError, match="backend"):
         population_ranking(obj, viol, backend="nope")
     with pytest.raises(ValueError, match="backend"):
         rank_select_rerank(obj, viol, 2, backend="nope")
+
+
+@pytest.mark.parametrize("platform,resolved", [("tpu", "kernel"),
+                                                ("cpu", "sweep")])
+def test_auto_is_the_kernel_on_tpu_only(monkeypatch, platform, resolved):
+    from repro.kernels.pop_ranking import ops
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: platform)
+    assert ops._resolve("auto") == resolved
+    assert ops._resolve(None) == resolved
 
 
 def test_sweep_is_two_objective_only():
@@ -93,6 +103,36 @@ def test_sweep_matches_matrix_edge_cases(case):
                                   err_msg=f"{case}: crowding differs")
 
 
+@pytest.mark.parametrize("case", sorted(EDGE_CASES))
+def test_kernel_matches_matrix_edge_cases(case):
+    """The staircase pass as a Pallas kernel (interpret mode) gives the
+    oracle's ranks and crowding on every edge-case population."""
+    obj, viol = EDGE_CASES[case]
+    obj, viol = jnp.asarray(obj), jnp.asarray(viol)
+    rank_m, crowd_m = evaluate_ranking(obj, viol)
+    rank_k, crowd_k = population_ranking(obj, viol, backend="interpret")
+    np.testing.assert_array_equal(np.asarray(rank_m), np.asarray(rank_k),
+                                  err_msg=f"{case}: ranks differ")
+    np.testing.assert_array_equal(np.asarray(crowd_m), np.asarray(crowd_k),
+                                  err_msg=f"{case}: crowding differs")
+
+
+@pytest.mark.parametrize("P", [130, 512])
+def test_kernel_matches_sweep_past_one_lane_row(P):
+    """Pools wider than one 128-lane row of the kernel's staircase: a
+    many-front pool with duplicates and mixed feasibility ranks as the
+    scan does, in the (μ+λ) tail too."""
+    rng = np.random.default_rng(P)
+    obj = jnp.asarray((rng.integers(0, 24, (P, 2)) / 24.0)
+                      .astype(np.float32))
+    viol = jnp.asarray(np.maximum(0.0, rng.random(P).astype(np.float32)
+                                  - 0.8))
+    for a, b in zip(rank_select_rerank(obj, viol, P // 2, backend="sweep"),
+                    rank_select_rerank(obj, viol, P // 2,
+                                       backend="interpret")):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_rank_select_rerank_backends_agree(seed):
     """The whole (μ+λ) tail — survivors and their subset re-ranking —
@@ -129,6 +169,25 @@ def test_sweep_vmaps_without_cross_lane_coupling():
                                                             viols[i])))
 
 
+def test_kernel_vmaps_without_cross_lane_coupling():
+    """Under ``vmap`` (the suite's and the server's lanes) the kernel ranks
+    each lane as the unbatched scan does."""
+    rng = np.random.default_rng(4)
+    many = (rng.integers(0, 4, (32, 2)) / 4.0).astype(np.float32)
+    single = np.stack([np.arange(32) / 32.0,
+                       (31 - np.arange(32)) / 32.0], axis=1).astype(np.float32)
+    objs = jnp.asarray(np.stack([many, single]))
+    viols = jnp.asarray(np.stack([np.zeros(32, np.float32),
+                                  np.where(np.arange(32) % 3 == 0, 0.5, 0.0)
+                                  .astype(np.float32)]))
+    batched = jax.vmap(lambda o, v: sweep_rank(o, v, kernel=True,
+                                               interpret=True))(objs, viols)
+    for i in range(2):
+        np.testing.assert_array_equal(np.asarray(batched[i]),
+                                      np.asarray(sweep_rank(objs[i],
+                                                            viols[i])))
+
+
 # -- whole-run equivalence ---------------------------------------------------
 
 def _run(ds, **kw):
@@ -146,6 +205,12 @@ def test_trainer_sweep_vs_matrix(bc_dataset, dedup):
     s_a = _run(bc_dataset, dedup=dedup, ranking_backend="auto")
     assert_states_equal(s_m, s_s, msg=f"sweep dedup={dedup}")
     assert_states_equal(s_s, s_a, msg=f"auto dedup={dedup}")
+
+
+def test_trainer_kernel_vs_sweep(bc_dataset):
+    s_s = _run(bc_dataset, ranking_backend="sweep")
+    s_k = _run(bc_dataset, ranking_backend="interpret")
+    assert_states_equal(s_s, s_k, msg="kernel")
 
 
 def test_run_batch_sweep_vs_matrix(bc_dataset):

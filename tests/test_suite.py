@@ -217,3 +217,76 @@ def test_pad_problem_rejects_jnp_backend(two_datasets):
                                  bc.y_train, cfg)
     with pytest.raises(ValueError, match="count-based"):
         engine.pad_problem(p, spec_pad)
+
+
+def test_second_sharded_call_traces_nothing(two_datasets):
+    """The mesh path builds its bucket program once per (generations,
+    mesh, axis names): a second call with the same shapes traces and
+    lowers no program at all, and the shared memo is hit, not grown."""
+    cfg = GAConfig(pop_size=8, generations=2)
+    problems = _problems(two_datasets, cfg)
+    mesh = jax.make_mesh((1,), ("data",))
+    sweep.run_suite(problems, [0, 2], mesh=mesh)
+    built = sweep._sharded_program.cache_info().currsize
+    lowered = []
+
+    def listen(event, secs, fun_name=None, **_):
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            lowered.append(fun_name)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        again = sweep.run_suite(problems, [0, 2], mesh=jax.make_mesh(
+            (1,), ("data",)))
+        jax.block_until_ready(again.states.pop)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert lowered == []
+    assert sweep._sharded_program.cache_info().currsize == built
+
+
+@pytest.mark.parametrize("factor", [1.0, None])
+def test_fitness_terms_match_the_shape_count(two_datasets, factor):
+    """``SuiteResult.fitness_terms``: real terms at each dataset's own rows
+    and topology, run terms at the padded topology and the sample bound
+    of the dispatch each dataset ran in, in whole 128-row tiles — two
+    buckets with the default factor, one shared bound without."""
+    cfg = GAConfig(pop_size=8, generations=2)
+    result = sweep.run_suite(_problems(two_datasets, cfg), [0, 1],
+                             sample_bucket_factor=factor)
+    rows = [ds.x_train.shape[0] for ds in two_datasets]
+    assert rows[0] != rows[1]
+    own = [10 * 3 + 3 * 2, 11 * 2 + 2 * 6]         # 10-3-2 and 11-2-6
+    padded = 11 * 3 + 3 * 6                          # 11-3-6
+    bound = rows if factor else [max(rows)] * 2
+    real = run = 0
+    for d in range(2):
+        evals = sum(result.unique_evals(i) for i in result.cells_of(d))
+        real += evals * rows[d] * own[d]
+        run += evals * -(-bound[d] // 128) * 128 * padded
+    assert result.fitness_terms() == (real, run)
+
+
+def test_second_call_without_mesh_traces_nothing(two_datasets):
+    """The one-device path reuses its programs on a second call too: the
+    padded spec it builds anew compares equal to the first call's by its
+    topology, so the programs' static keys match."""
+    cfg = GAConfig(pop_size=8, generations=2)
+    problems = _problems(two_datasets, cfg)
+    first = sweep.run_suite(problems, [0, 2])
+    assert sweep.suite_spec(problems) == first.spec
+    assert hash(sweep.suite_spec(problems)) == hash(first.spec)
+    assert sweep.suite_spec(problems[:1]) != first.spec
+    lowered = []
+
+    def listen(event, secs, fun_name=None, **_):
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            lowered.append(fun_name)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        again = sweep.run_suite(problems, [0, 2])
+        jax.block_until_ready(again.states.pop)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert lowered == []

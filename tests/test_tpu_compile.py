@@ -25,6 +25,7 @@ from repro.data.tabular import TOPOLOGIES
 from repro.kernels import BackendPolicy
 from repro.kernels.pop_generation import pop_generation_kernel
 from repro.kernels.pop_mlp.kernel import pop_mlp_correct, pop_mlp_correct_mc
+from repro.kernels.pop_ranking import rank_select_rerank
 from repro.kernels.pop_variation import pop_variation_kernel
 
 P = 256
@@ -95,6 +96,16 @@ def test_kernel_compiles_for_v5e(one_chip, dataset, n, kernel):
     _compile(fn, _args(one_chip, *shapes))
 
 
+@pytest.mark.parametrize("pool", [2 * P, P])
+def test_ranking_tail_compiles_for_v5e(one_chip, pool):
+    """The (μ+λ) ranking tail with its staircase passes as Pallas calls,
+    at the pool (2P) and survivor (P) widths, vmapped over 2 lanes."""
+    f32 = jnp.float32
+    fn = jax.vmap(lambda o, v: rank_select_rerank(o, v, pool // 2,
+                                                  backend="kernel"))
+    _compile(fn, _args(one_chip, ((2, pool, 2), f32), ((2, pool), f32)))
+
+
 def test_vmapped_generation_compiles_for_v5e(one_chip):
     """One generation of the engine, vmapped over 4 lanes as ``run_suite``
     and ``SearchServer`` run it, with every Pallas backend forced on:
@@ -103,7 +114,8 @@ def test_vmapped_generation_compiles_for_v5e(one_chip):
     n = 1488
     cfg = GAConfig(pop_size=P, batch_axis=engine.BATCH_AXIS,
                    backends=BackendPolicy(fitness="kernel", variation="kernel",
-                                          generation="kernel"))
+                                          generation="kernel",
+                                          ranking="kernel"))
     rng = np.random.default_rng(0)
     problem = engine.Problem.from_data(
         MLPTopology(sizes), rng.random((n, sizes[0]), np.float32),
@@ -120,3 +132,47 @@ def test_vmapped_generation_compiles_for_v5e(one_chip):
                     axis_name=engine.BATCH_AXIS)
     _compile(step, (lanes(problem), lanes(state)))
 
+
+
+def test_sharded_suite_bucket_compiles_for_v5e_mesh(topo):
+    """One bucket of the paper suite as ``run_suite(..., mesh=...)`` runs
+    it on a four-chip host: 8 pendigits cells in the padded 21-5-10
+    layout at 7,696 rows, split over a 2x2 v5e's four chips, with every
+    Pallas backend forced on. The megakernel stays in the program under
+    ``shard_map`` ∘ ``vmap``."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from repro.core import sweep
+
+    cells, n = 8, 7696
+    cfg = GAConfig(pop_size=P, generations=2,
+                   backends=BackendPolicy(fitness="kernel", variation="kernel",
+                                          generation="kernel",
+                                          ranking="kernel"))
+    rng = np.random.default_rng(0)
+    problems = [engine.Problem.from_data(
+        MLPTopology(TOPOLOGIES[d]), rng.random((64, TOPOLOGIES[d][0]),
+                                               np.float32),
+        rng.integers(0, TOPOLOGIES[d][-1], 64), cfg)
+        for d in ("breast_cancer", "cardio", "pendigits", "redwine",
+                  "whitewine")]
+    spec = sweep.suite_spec(problems)
+    assert spec.topo.sizes == (21, 5, 10)
+    pendigits = engine.Problem.from_data(
+        MLPTopology(TOPOLOGIES["pendigits"]),
+        rng.random((n, 16), np.float32), rng.integers(0, 10, n), cfg)
+    lane = sweep.pad_lane(pendigits, spec, n)
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("data",))
+    split = NamedSharding(mesh, PartitionSpec("data"))
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=split),
+            tree)
+
+    stacked = jax.eval_shape(lambda: sweep.stack_problems([lane] * cells))
+    seeds = jax.ShapeDtypeStruct((cells,), jnp.int32)
+    doping = jax.ShapeDtypeStruct((cells, 25, spec.n_genes), jnp.int32)
+    program = sweep._sharded_program(sweep._run_suite_cells, 2, mesh,
+                                     ("data",), (False, False, False))
+    compiled = program.lower(sds(stacked), *sds((seeds, doping))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
