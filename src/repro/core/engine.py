@@ -100,7 +100,9 @@ class GAConfig:
     generation_backend: str | None = None
     ranking_backend: str | None = None
     # population tile — shared by the fitness "ref" backend and the
-    # variation Pallas kernel (one knob tiles both hot paths)
+    # variation Pallas kernel; the fitness kernels and the generation
+    # megakernel derive their own tiles from the shapes
+    # (``kernels.pop_mlp.kernel.sample_tile``)
     pop_tile: int = 64
     sample_tile: int = 256           # sample tile ("ref" backend)
     # duplicate-chromosome eval caching: True/"cache" carries a cross-

@@ -57,6 +57,7 @@ from jax import shard_map
 from . import engine
 from . import genome as genome_mod
 from .engine import GAState, Problem
+from ..kernels.pop_mlp.kernel import row_group
 
 
 def grid_cells(seeds, crossover_rates=None, mutation_rates=None,
@@ -440,15 +441,17 @@ class SuiteResult:
         the chromosome rows the cells really evaluated (``unique_evals``)
         and every device instance. Real: a cell's own training rows × its
         own Σ fan_in·fan_out. Run: its bucket's sample bound in whole
-        sample tiles of the Pallas kernels × Σ fan_in·fan_out of the
-        padded spec, as its lane computed them. 1 − real/run is the share
-        of the fitness work that falls on padding."""
+        row groups of the Pallas kernels (``pop_mlp.kernel.row_group``:
+        their row loop stops at the first group past the bound) × Σ
+        fan_in·fan_out of the padded spec, as its lane computed them.
+        1 − real/run is the share of the fitness work that falls on
+        padding."""
         def weights(spec):
             s = spec.topo.sizes
             return sum(a * b for a, b in zip(s, s[1:]))
 
         cfg = self.problems[0].cfg
-        tile = min(cfg.sample_tile, 128)
+        tile = row_group(self.spec)
         k = cfg.n_device_samples if engine.variation_on(cfg) else 1
         evals = (np.asarray(self.init_evals, np.int64)
                  + np.asarray(self.aux[2], np.int64).sum(axis=1))

@@ -7,9 +7,14 @@ HBM: at sample-grid step 0 the kernel runs the variation math of
 mutation → clip) and writes the child block to its output ref; that block
 then stays resident in VMEM while the sample grid axis sweeps the dataset,
 each step running the integer forward pass of ``pop_mlp.kernel`` on it and
-accumulating correct counts (tail samples masked, padded-topology output
-columns pinned below any real logit, all-padding sample tiles skipped via
-``pl.when`` — bit-exact, they could only add zero).
+accumulating correct counts (padded-topology output columns pinned below
+any real logit, samples past the valid bound skipped — bit-exact, they
+could only add zero).
+
+The tile is gene-major, as in ``pop_mlp.kernel``: a (G_pad, 128) block,
+one child per lane, so the variation's per-gene tables are (G_pad, 1)
+columns, the child index is a lane iota, and every gene read of the
+forward pass is a sublane broadcast of one row of the child block.
 
 Grid iteration is row-major (the sample axis innermost), so for every
 population tile the variation step runs before any fitness step reads the
@@ -31,27 +36,33 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ...core.genome import GenomeSpec
-from ..pop_mlp.kernel import (count_correct, count_correct_mc,
-                              sample_operands, sample_specs, valid_samples)
+from ..pop_mlp.kernel import (LANES, build_instances, count_tile,
+                              gene_column, gene_major, padded_sizes,
+                              sample_operands, sample_specs, scratch_shapes,
+                              write_counts)
 from ..pop_variation.kernel import _slot_uniform
 
 
 def _kernel(a_ref, b_ref, do_ref, low_ref, high_ref, ismask_ref, bits_ref,
             ids_ref, keys_ref, pm_ref, x_ref, y_ref, samp_ref, om_ref,
-            *refs, spec: GenomeSpec, bp: int, half: int,
-            bs: int, n_valid: int, mc: bool):
-    # trailing refs: [dev_ref] (device-variation MC only), child_ref, cnt_ref
-    child_ref, cnt_ref = refs[-2], refs[-1]
+            *refs, spec: GenomeSpec, half: int, bs: int, mc: bool):
+    # trailing refs: [dev_ref] (device-variation MC only), child_ref,
+    # cnt_ref, then the scratch: cnt_acc, inst_ref
+    dev_ref = refs[0] if mc else None
+    child_ref, cnt_ref, cnt_acc, inst_ref = refs[-4:]
     # program_id must stay outside the traced-cond bodies: the interpret-mode
     # impl only substitutes it at kernel top level (see pop_mlp.kernel)
-    row_start = pl.program_id(0) * bp
+    row_start = pl.program_id(0) * LANES
     start = pl.program_id(1) * bs
+    last = pl.num_programs(1) - 1
 
     @pl.when(pl.program_id(1) == 0)
     def _variation():
-        rows = (row_start
-                + jax.lax.broadcasted_iota(jnp.int32, a_ref.shape, 0))
-        gid = jnp.broadcast_to(ids_ref[...], a_ref.shape).astype(jnp.uint32)
+        # genes on sublanes, children on lanes: the per-gene tables are
+        # (G_pad, 1) columns, the per-child gate a (1, LANES) row
+        shape = a_ref.shape
+        rows = row_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        gid = jnp.broadcast_to(ids_ref[...], shape)
 
         # crossover: the swap draw is addressed by the parent *pair* index
         pair = rows % half
@@ -73,31 +84,30 @@ def _kernel(a_ref, b_ref, do_ref, low_ref, high_ref, ismask_ref, bits_ref,
                           ).astype(jnp.int32)
         mutated = jnp.where(ismask_ref[...] > 0, flipped, reset)
         child = jnp.where(u_do < pm_ref[0, 0], mutated, child)
-        child_ref[...] = jnp.clip(child, lo, hi - 1)
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+        child = jnp.clip(child, lo, hi - 1)
+        child_ref[...] = child
+        cnt_acc[...] = jnp.zeros_like(cnt_acc)
+        # the decoded children (the K perturbed instances under MC) are
+        # built once per population tile and stay in VMEM for every step
+        build_instances(child, spec, inst_ref, dev_ref, hi)
 
     # suite fast path: all-padding sample tiles (label −1) are skipped
     @pl.when(start < samp_ref[0, 0])
     def _fitness():
-        args = (x_ref[...], y_ref[...], om_ref[...],
-                valid_samples(start, n_valid, bp, bs), spec)
-        if mc:
-            # device-variation MC: the child block stays resident in VMEM
-            # while the K perturbed instances each rerun the forward pass
-            cnt_ref[...] += count_correct_mc(child_ref[...], refs[0][...],
-                                             high_ref[...], *args)
-        else:
-            cnt_ref[...] += count_correct(child_ref[...], *args)
+        count_tile(inst_ref, x_ref, y_ref, om_ref, cnt_acc, start,
+                   samp_ref[0, 0], spec)
+
+    @pl.when(pl.program_id(1) == last)
+    def _emit():
+        write_counts(cnt_acc, cnt_ref)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("spec", "bp", "bs", "interpret"))
+@functools.partial(jax.jit, static_argnames=("spec", "interpret"))
 def pop_generation_kernel(a_rows, b_rows, do_rows, table_low, table_high,
                           table_is_mask, table_mask_bits, table_ids,
                           slot_keys, pm_gene, x_int, labels, *,
-                          spec: GenomeSpec, bp: int = 8, bs: int = 128,
-                          interpret: bool = False, n_valid_samples=None,
-                          out_mask=None, dev=None):
+                          spec: GenomeSpec, interpret: bool = False,
+                          n_valid_samples=None, out_mask=None, dev=None):
     """Pre-gathered parent frames + dataset → (children, correct counts).
 
     a_rows/b_rows: (P, G) int32 no-swap / swap sources per child row (the
@@ -114,52 +124,50 @@ def pop_generation_kernel(a_rows, b_rows, do_rows, table_low, table_high,
         ``table_high`` bounds already on board.
     Returns ((P, G) int32 children, (P,) — or (P, K) with ``dev`` —
     int32 correct counts).
+
+    The kernel runs gene-major (``pop_mlp.kernel``): the parent frames go
+    in as (G_pad, P_pad) blocks of one child per lane, and the children
+    come back the same way; the population is padded to whole lane tiles
+    (the padded children compute garbage that is sliced off).
     """
     P, G = a_rows.shape
-    half = P // 2
-    S = x_int.shape[0]
-    n_out = spec.topo.sizes[-1]
-    bp = min(bp, P)
-    pad_p = (bp - P % bp) % bp
-    if pad_p:                     # padded rows compute garbage; sliced off
-        a_rows = jnp.pad(a_rows, ((0, pad_p), (0, 0)))
-        b_rows = jnp.pad(b_rows, ((0, pad_p), (0, 0)))
-        do_rows = jnp.pad(do_rows.astype(jnp.int32), (0, pad_p))
-    xt, y, samp, om, n_s = sample_operands(x_int, labels, bs,
-                                           n_valid_samples, out_mask, n_out)
-    row2d = lambda arr: jnp.asarray(arr, jnp.int32).reshape(-1, 1)
-    gene2d = lambda arr, dt: jnp.asarray(arr, dt).reshape(1, G)
+    G_pad, P_pad = padded_sizes(P, G)
+    samples, bs, n_s = sample_operands(x_int, labels, spec, n_valid_samples,
+                                       out_mask)
+    col = functools.partial(gene_column, n_genes=G_pad)
     nc = 1 if dev is None else dev.shape[0]
-    dev_specs = ([] if dev is None
-                 else [pl.BlockSpec((nc, G), lambda i, j: (0, 0))])
-    dev_ops = () if dev is None else (jnp.asarray(dev, jnp.int32),)
+    mc = dev is not None
+    block = pl.BlockSpec((G_pad, LANES), lambda i, j: (0, i))
+    column = pl.BlockSpec((G_pad, 1), lambda i, j: (0, 0))
     children, counts = pl.pallas_call(
-        functools.partial(_kernel, spec=spec, bp=bp, half=half, bs=bs,
-                          n_valid=S, mc=dev is not None),
-        grid=((P + pad_p) // bp, n_s),
+        functools.partial(_kernel, spec=spec, half=P // 2, bs=bs, mc=mc),
+        grid=(P_pad // LANES, n_s),
         in_specs=[
-            pl.BlockSpec((bp, G), lambda i, j: (i, 0)),     # a_rows
-            pl.BlockSpec((bp, G), lambda i, j: (i, 0)),     # b_rows
-            pl.BlockSpec((bp, 1), lambda i, j: (i, 0)),     # do-crossover
-            pl.BlockSpec((1, G), lambda i, j: (0, 0)),      # low
-            pl.BlockSpec((1, G), lambda i, j: (0, 0)),      # high
-            pl.BlockSpec((1, G), lambda i, j: (0, 0)),      # is_mask
-            pl.BlockSpec((1, G), lambda i, j: (0, 0)),      # mask_bits
-            pl.BlockSpec((1, G), lambda i, j: (0, 0)),      # draw ids
+            block,                                          # a_rows
+            block,                                          # b_rows
+            pl.BlockSpec((1, LANES), lambda i, j: (0, i)),  # do-crossover
+            column,                                         # low
+            column,                                         # high
+            column,                                         # is_mask
+            column,                                         # mask_bits
+            column,                                         # draw ids
             pl.BlockSpec((3, 2), lambda i, j: (0, 0)),      # slot keys
             pl.BlockSpec((1, 1), lambda i, j: (0, 0)),      # pm_gene
-            *sample_specs(x_int.shape[1], bs, n_out),
-            *dev_specs,                                     # device deltas
+            *sample_specs(x_int.shape[1], bs),
+            *([pl.BlockSpec((G_pad, nc), lambda i, j: (0, 0))]  # deltas
+              if mc else []),
         ],
-        out_specs=[pl.BlockSpec((bp, G), lambda i, j: (i, 0)),
-                   pl.BlockSpec((bp, nc), lambda i, j: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((P + pad_p, G), jnp.int32),
-                   jax.ShapeDtypeStruct((P + pad_p, nc), jnp.int32)],
+        out_specs=[block, pl.BlockSpec((nc, LANES), lambda i, j: (0, i))],
+        out_shape=[jax.ShapeDtypeStruct((G_pad, P_pad), jnp.int32),
+                   jax.ShapeDtypeStruct((nc, P_pad), jnp.int32)],
+        scratch_shapes=scratch_shapes(nc, G_pad),
         interpret=interpret,
-    )(a_rows, b_rows, row2d(do_rows), gene2d(table_low, jnp.int32),
-      gene2d(table_high, jnp.int32), gene2d(table_is_mask, jnp.int32),
-      gene2d(table_mask_bits, jnp.int32), gene2d(table_ids, jnp.uint32),
+    )(gene_major(a_rows, G_pad, P_pad), gene_major(b_rows, G_pad, P_pad),
+      jnp.pad(jnp.asarray(do_rows, jnp.int32), (0, P_pad - P)).reshape(1, -1),
+      col(table_low), col(table_high, fill=1), col(table_is_mask),
+      col(table_mask_bits), col(table_ids, dtype=jnp.uint32),
       jnp.asarray(slot_keys, jnp.uint32),
       jnp.asarray(pm_gene, jnp.float32).reshape(1, 1),
-      xt, y, samp, om, *dev_ops)
-    return children[:P], (counts[:P, 0] if dev is None else counts[:P])
+      *samples, *([gene_major(dev, G_pad, nc)] if mc else []))
+    counts = counts[:, :P].T
+    return children[:G, :P].T, (counts[:, 0] if dev is None else counts)

@@ -76,8 +76,7 @@ def _generation_kernel(problem, state, interpret: bool, active=None):
     children, child_counts = pop_generation_kernel(
         a_rows, b_rows, do_rows, t.low, t.high, t.is_mask, t.mask_bits,
         t.ids, var_keys, problem.mutation_rate_gene, problem.x_int,
-        problem.labels, spec=problem.spec, bp=min(cfg.pop_tile, 8),
-        bs=min(cfg.sample_tile, 128), interpret=interpret,
+        problem.labels, spec=problem.spec, interpret=interpret,
         n_valid_samples=n_samp, out_mask=problem.out_mask, dev=dev)
     with jax.named_scope("ga.objectives"):
         pop = jnp.concatenate([state.pop, children], axis=0)

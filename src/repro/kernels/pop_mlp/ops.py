@@ -52,6 +52,9 @@ def population_correct(pop, x_int, labels, *, spec, backend=None,
     bounds) bounds the perturbed exponents per gene. The "jnp" oracle has
     no instance axis and rejects ``dev``.
 
+    ``pop_tile``/``sample_tile`` tile the "ref" path only: the Pallas
+    kernels derive their tiles from the shapes (``kernel.sample_tile``).
+
     ``use_kernel``/``interpret`` are the legacy knobs (pre-dispatcher API)
     and take precedence over ``backend`` when given."""
     if use_kernel is not None:
@@ -71,7 +74,6 @@ def population_correct(pop, x_int, labels, *, spec, backend=None,
         if backend == "kernel" or backend == "interpret":
             return pop_mlp_correct_mc(
                 pop, x_int, labels, dev, gene_high, spec=spec,
-                bp=min(pop_tile, 8), bs=min(sample_tile, 128),
                 interpret=(backend == "interpret" if interpret is None
                            else interpret),
                 n_valid_rows=n_valid_rows, n_valid_samples=n_valid_samples,
@@ -83,8 +85,7 @@ def population_correct(pop, x_int, labels, *, spec, backend=None,
             out_mask=out_mask)
     if backend == "kernel" or backend == "interpret":
         return pop_mlp_correct(
-            pop, x_int, labels, spec=spec, bp=min(pop_tile, 8),
-            bs=min(sample_tile, 128),
+            pop, x_int, labels, spec=spec,
             interpret=(backend == "interpret" if interpret is None
                        else interpret),
             n_valid_rows=n_valid_rows, n_valid_samples=n_valid_samples,

@@ -7,13 +7,18 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core import GAConfig, GATrainer
+from repro.core import GAConfig, GATrainer, engine
+from repro.core import genome as genome_mod
 from repro.core.dedup import dedup_eval, unique_rows
 from repro.core.genome import MLPTopology, GenomeSpec
 from repro.core.nsga2 import (dominance_matrix, evaluate_ranking,
                               subset_ranking, survivor_select)
+from repro.kernels.pop_generation import pop_generation_kernel
 from repro.kernels.pop_mlp import (population_correct, pop_mlp_correct,
                                    pop_mlp_correct_ref, pop_mlp_correct_tiled)
+from repro.kernels.pop_mlp.kernel import pop_mlp_correct_mc, sample_tile
+from repro.kernels.pop_variation.ops import _VARIATION_SLOTS
+from repro.kernels.pop_variation.ref import pop_variation_ref
 
 
 @pytest.fixture(scope="module")
@@ -39,17 +44,110 @@ def test_tiled_matches_oracle(small_problem, S, pop_tile, sample_tile):
 
 def test_kernel_matches_tiled_under_sample_tiling(small_problem):
     spec, pop, x, y = small_problem
-    ref = pop_mlp_correct_ref(pop, x, y, spec=spec)
-    out = pop_mlp_correct(pop, x, y, spec=spec, bp=8, bs=64, interpret=True)
+    S = 2 * 2048 + 37                  # past the sample tile's VMEM cap
+    xs = jnp.tile(x, (14, 1))[:S]
+    ys = jnp.tile(y, 14)[:S]
+    assert S % sample_tile(spec, S) and S > 2 * sample_tile(spec, S)
+    ref = pop_mlp_correct_ref(pop, xs, ys, spec=spec)
+    out = pop_mlp_correct(pop, xs, ys, spec=spec, interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
 def test_kernel_pads_nondividing_population(small_problem):
     spec, pop, x, y = small_problem
     ref = pop_mlp_correct_ref(pop[:6], x, y, spec=spec)
-    out = pop_mlp_correct(pop[:6], x, y, spec=spec, bp=4, bs=128,
-                          interpret=True)
+    out = pop_mlp_correct(pop[:6], x, y, spec=spec, interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+# -- gene-major kernels vs the jnp oracles -----------------------------------
+
+# P: population (200 pads the lane axis); S: samples (4133 spans three
+# sample tiles, the last one partial); pad: rows of the suite layout the
+# 10-3-2 problem is embedded into (12-4-5, out_mask, n_valid_samples 301);
+# K: device instances
+ORIENTATION_CASES = {
+    "p256": dict(P=256, S=301),
+    "p200": dict(P=200, S=301),
+    "tail_tile": dict(P=200, S=4133),
+    "suite_padded": dict(P=200, S=301, pad=4133),
+    "mc_k8": dict(P=200, S=301, K=8),
+}
+
+
+def _orientation_case(P, S, pad=None, K=None):
+    """(problem, population, deltas) of one case; the problem is padded
+    into the suite layout when ``pad`` is given."""
+    topo = MLPTopology((10, 3, 2))
+    rng = np.random.default_rng(P + S)
+    problem = engine.Problem.from_data(
+        topo, rng.random((S, 10), np.float32), rng.integers(0, 2, S),
+        GAConfig(pop_size=P))
+    pop = np.asarray(problem.spec.random(jax.random.PRNGKey(P), P))
+    if pad is not None:
+        padded = GenomeSpec(MLPTopology((12, 4, 5)))
+        pos = genome_mod.pad_positions(problem.spec, padded)
+        pop = genome_mod.pad_genomes(pop, pos, padded.n_genes)
+        problem = engine.pad_problem(problem, padded, pad, pos)
+    dev = None
+    if K is not None:
+        exps = np.zeros(problem.spec.n_genes, bool)
+        for sl in problem.spec.layers:
+            exps[sl.exps] = True
+        dev = np.where(exps, rng.integers(-1, 2, (K, exps.size)), 0)
+        dev[0] = 0                                   # the nominal device
+    return problem, jnp.asarray(pop, jnp.int32), dev
+
+
+def _oracle_counts(problem, pop, dev):
+    """(P,) — or (P, K) — counts of the untiled jnp oracle, each device
+    instance perturbed on its own (``genome.apply_device_deltas``)."""
+    def counts(rows):
+        return np.asarray(pop_mlp_correct_ref(
+            rows, problem.x_int, problem.labels, spec=problem.spec,
+            out_mask=problem.out_mask))
+    if dev is None:
+        return counts(pop)
+    return np.stack([counts(genome_mod.apply_device_deltas(
+        pop, jnp.asarray(d), problem.genes.high)) for d in dev], axis=1)
+
+
+@pytest.mark.parametrize("kernel", ["fitness", "generation"])
+@pytest.mark.parametrize("case", list(ORIENTATION_CASES))
+def test_gene_major_kernels_match_oracles(case, kernel):
+    """The fitness kernels and the generation megakernel, in interpret
+    mode, equal the jnp oracles bit for bit: lane-padded populations,
+    partial sample tiles, the suite layout's output mask and sample bound
+    (tiles past it skipped), and K = 8 device instances."""
+    problem, pop, dev = _orientation_case(**ORIENTATION_CASES[case])
+    n_valid = problem.n_valid_samples
+    if case == "suite_padded":             # the tiles past 301 are skipped
+        assert 301 == int(n_valid) < sample_tile(problem.spec, 4133)
+    kw = dict(spec=problem.spec, interpret=True, n_valid_samples=n_valid,
+              out_mask=problem.out_mask)
+    t = problem.genes
+    if kernel == "fitness":
+        rows = pop
+        out = (pop_mlp_correct(pop, problem.x_int, problem.labels, **kw)
+               if dev is None else
+               pop_mlp_correct_mc(pop, problem.x_int, problem.labels,
+                                  jnp.asarray(dev), t.high, **kw))
+    else:
+        key = jax.random.PRNGKey(11)
+        P = pop.shape[0]
+        pa, pb = pop[: P // 2], pop[P // 2:]
+        do_cx = jax.random.uniform(jax.random.PRNGKey(12), (P // 2,)) < 0.7
+        pm = jnp.float32(0.05)
+        rows, out = pop_generation_kernel(
+            jnp.concatenate([pa, pb]), jnp.concatenate([pb, pa]),
+            jnp.concatenate([do_cx, do_cx]), t.low, t.high, t.is_mask,
+            t.mask_bits, t.ids, genome_mod._slot_keys(key, _VARIATION_SLOTS),
+            pm, problem.x_int, problem.labels,
+            dev=None if dev is None else jnp.asarray(dev), **kw)
+        children = pop_variation_ref(key, pa, pb, do_cx[:, None], t, pm)
+        np.testing.assert_array_equal(np.asarray(rows), np.asarray(children))
+    np.testing.assert_array_equal(np.asarray(out),
+                                  _oracle_counts(problem, rows, dev))
 
 
 @pytest.mark.parametrize("backend", ["ref", "interpret", "jnp"])

@@ -51,7 +51,7 @@ def test_pop_mlp_sweep(sizes, S, key):
     x = jax.random.randint(jax.random.PRNGKey(1), (S, sizes[0]), 0, 16)
     y = jax.random.randint(jax.random.PRNGKey(2), (S,), 0, sizes[-1])
     ref = pop_mlp_correct_ref(pop, x, y, spec=spec)
-    out = pop_mlp_correct(pop, x, y, spec=spec, bp=4, bs=128, interpret=True)
+    out = pop_mlp_correct(pop, x, y, spec=spec, interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 

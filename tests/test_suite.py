@@ -19,6 +19,7 @@ from repro.core.genome import (MLPTopology, GenomeSpec, max_topology,
 from repro.core.operators import make_offspring
 from repro.data import load_dataset
 from repro.kernels.pop_mlp import population_correct
+from repro.kernels.pop_mlp.kernel import row_group
 
 
 STATE_FIELDS = ("pop", "obj", "viol", "rank", "crowd", "counts", "key", "gen")
@@ -249,8 +250,9 @@ def test_second_sharded_call_traces_nothing(two_datasets):
 def test_fitness_terms_match_the_shape_count(two_datasets, factor):
     """``SuiteResult.fitness_terms``: real terms at each dataset's own rows
     and topology, run terms at the padded topology and the sample bound
-    of the dispatch each dataset ran in, in whole 128-row tiles — two
-    buckets with the default factor, one shared bound without."""
+    of the dispatch each dataset ran in, in whole row groups of the
+    Pallas kernels' row loop — two buckets with the default factor, one
+    shared bound without."""
     cfg = GAConfig(pop_size=8, generations=2)
     result = sweep.run_suite(_problems(two_datasets, cfg), [0, 1],
                              sample_bucket_factor=factor)
@@ -258,12 +260,14 @@ def test_fitness_terms_match_the_shape_count(two_datasets, factor):
     assert rows[0] != rows[1]
     own = [10 * 3 + 3 * 2, 11 * 2 + 2 * 6]         # 10-3-2 and 11-2-6
     padded = 11 * 3 + 3 * 6                          # 11-3-6
+    group = row_group(result.spec)
+    assert group == 16              # 3 + 6 live planes + 2 temporaries
     bound = rows if factor else [max(rows)] * 2
     real = run = 0
     for d in range(2):
         evals = sum(result.unique_evals(i) for i in result.cells_of(d))
         real += evals * rows[d] * own[d]
-        run += evals * -(-bound[d] // 128) * 128 * padded
+        run += evals * -(-bound[d] // group) * group * padded
     assert result.fitness_terms() == (real, run)
 
 

@@ -11,6 +11,8 @@ module-scoped fixture — never at import — so that only the pytest worker
 that runs this file loads the TPU compiler.
 """
 import functools
+import os
+import re
 
 import numpy as np
 import pytest
@@ -24,7 +26,8 @@ from repro.core.genome import GenomeSpec, MLPTopology
 from repro.data.tabular import TOPOLOGIES
 from repro.kernels import BackendPolicy
 from repro.kernels.pop_generation import pop_generation_kernel
-from repro.kernels.pop_mlp.kernel import pop_mlp_correct, pop_mlp_correct_mc
+from repro.kernels.pop_mlp.kernel import (pop_mlp_correct, pop_mlp_correct_mc,
+                                          row_group)
 from repro.kernels.pop_ranking import rank_select_rerank
 from repro.kernels.pop_variation import pop_variation_kernel
 
@@ -37,13 +40,29 @@ KERNELS = ["pop_mlp_correct", "pop_mlp_correct_mc", "pop_variation_kernel",
 
 
 @pytest.fixture(scope="module")
-def topo():
+def mosaic_dump(tmp_path_factory):
+    return tmp_path_factory.mktemp("mosaic")
+
+
+@pytest.fixture(scope="module")
+def topo(mosaic_dump):
     from jax.experimental import topologies
+    # Mosaic dumps its passes for every kernel compiled here (read by
+    # test_megakernel_reads_genes_without_lane_permutes); the TPU compiler
+    # reads its flags once, when the topology is first described
+    before = os.environ.get("LIBTPU_INIT_ARGS")
+    os.environ["LIBTPU_INIT_ARGS"] = (
+        f"{before or ''} --xla_mosaic_dump_to={mosaic_dump}").strip()
     try:
         return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        if before is None:
+            del os.environ["LIBTPU_INIT_ARGS"]
+        else:
+            os.environ["LIBTPU_INIT_ARGS"] = before
 
 
 @pytest.fixture(scope="module")
@@ -176,3 +195,43 @@ def test_sharded_suite_bucket_compiles_for_v5e_mesh(topo):
                                      ("data",), (False, False, False))
     compiled = program.lower(sds(stacked), *sds((seeds, doping))).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _row_loop(llo: str) -> str:
+    """The text of a compiled kernel's row loop: the ``scf.for`` that
+    carries the (8, 128) int32 hit block, or "" if it has none."""
+    lines = llo.splitlines()
+    start = next((i for i, line in enumerate(lines)
+                  if "scf.for" in line and "vector<8x128xi32>" in line), None)
+    if start is None:
+        return ""
+    depth = 0
+    for end in range(start, len(lines)):
+        depth += lines[end].count("{") - lines[end].count("}")
+        if depth <= 0 and end > start:
+            break
+    return "\n".join(lines[start:end + 1])
+
+
+def test_megakernel_reads_genes_without_lane_permutes(one_chip, mosaic_dump):
+    """The megakernel's gene reads are sublane broadcasts: in its compiled
+    row loop at pendigits' shapes (16-5-10, 130 shift-add terms), lane
+    permutes (``llo.vperm``) per term per (8 × 128) vreg stay below 0.5 —
+    what is left broadcasts the input features and the labels across the
+    lanes. With chromosomes on sublanes every term read three genes by a
+    lane permute each (~3.2 a term)."""
+    spec = GenomeSpec(MLPTopology(TOPOLOGIES["pendigits"]))
+    G, n, i32 = spec.n_genes, 7696, jnp.int32
+    shapes = ([((P, G), i32)] * 2 + [((P,), i32)] + [((G,), i32)] * 4
+              + [((G,), jnp.uint32), ((3, 2), jnp.uint32), ((), jnp.float32),
+                 ((n, 16), i32), ((n,), i32)])
+    seen = set(mosaic_dump.glob("*-post-finalize-llo.txt"))
+    _compile(lambda *a: pop_generation_kernel(*a, spec=spec),
+             _args(one_chip, *shapes))
+    dumps = set(mosaic_dump.glob("*-post-finalize-llo.txt")) - seen
+    loops = [_row_loop(d.read_text()) for d in dumps]
+    assert any(loops), "no row loop in the compiled megakernel"
+    rows = row_group(spec)
+    for loop in filter(None, loops):
+        n_vperm = len(re.findall(r"llo\.vperm\b", loop))
+        assert n_vperm / (130 * rows / 8) < 0.5, n_vperm
